@@ -146,16 +146,13 @@ def main(report, scenario=None):
     # float32 rates may quantize a completion one tick differently across
     # jax releases, so only the completion count is pinned — everything
     # else about this row is wall-time telemetry
-    from repro import jax_compat
-
-    if jax_compat.HAS_PALLAS:
-        n = 2_000
-        t0 = time.perf_counter()
-        res = fleet_point(spec, n, backend="pallas")
-        wall = time.perf_counter() - t0
-        done = int(np.isfinite(res.completed_at).sum())
-        fleet_row(report, "scaling/fleet_pallas_n2000", res, n, wall,
-                  derived=f"done={done}/{res.n} (float32 path: count-only pin)")
+    n = 2_000
+    t0 = time.perf_counter()
+    res = fleet_point(spec, n, backend="pallas")
+    wall = time.perf_counter() - t0
+    done = int(np.isfinite(res.completed_at).sum())
+    fleet_row(report, "scaling/fleet_pallas_n2000", res, n, wall,
+              derived=f"done={done}/{res.n} (float32 path: count-only pin)")
 
 
 if __name__ == "__main__":

@@ -334,6 +334,9 @@ def main() -> None:
     if args.list:
         list_benches()
         return
+    from repro.accel import enable_compile_cache
+
+    enable_compile_cache()
     scenario_path = Path(args.scenario).resolve() if args.scenario else None
     if args.trace is not None:
         if scenario_path is None:

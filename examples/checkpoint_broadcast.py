@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.core import (
     ClusterTopology, LocalSwarm, MetaInfo, broadcast_bundle, bundle_to_bytes,
-    coldstart_time,
+    coldstart_time, stripe_shards,
 )
 from repro.kernels.checksum import device_checksum, verify_replicas
 from repro.launch.mesh import make_test_mesh
@@ -37,12 +37,17 @@ def main() -> None:
           f"ud={swarm.ud_ratio:.1f} wall={time.perf_counter()-t0:.2f}s")
 
     print("\n--- collective-assisted: stripe + all-gather on a jax mesh ---")
-    mesh = make_test_mesh((1, 1), ("data", "model"))
+    n_dev = len(jax.devices())
+    mesh = make_test_mesh((n_dev, 1), ("data", "model"))
     replicated, ln = broadcast_bundle(payload, mesh, "data")
     assert bundle_to_bytes(replicated, ln) == payload
-    cs = device_checksum(replicated)
-    print(f"replicated on-mesh; device checksum={np.asarray(cs)} "
-          f"replicas_agree={verify_replicas([cs, cs])}")
+    # each replica is checked on its own device against the checksum of
+    # the payload's stripes; interpret mode is resolved from the platform
+    stripes = np.stack(stripe_shards(payload, n_dev)).reshape(n_dev, -1, 128)
+    want = device_checksum(jax.numpy.asarray(stripes))
+    sums = [device_checksum(s.data) for s in replicated.addressable_shards]
+    print(f"replicated on {n_dev} device(s); payload checksum="
+          f"{np.asarray(want)} replicas_match={verify_replicas([want, *sums])}")
 
     print("\n--- projected wall times, 512-host fleet, 1 TB checkpoint ---")
     topo = ClusterTopology(num_pods=2, hosts_per_pod=256)

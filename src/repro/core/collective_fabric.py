@@ -15,7 +15,9 @@ Two layers here:
 * a **functional JAX path** (`stripe_shards` / `allgather_bundle`) used by
   checkpoint broadcast: the bundle lives as a uint8 array sharded across the
   'data' axis, and one `jax.lax.all_gather` replicates it. Works on any
-  mesh; on TPU the gather rides the ICI rings.
+  mesh; on TPU the gather rides the ICI rings. Each stripe is laid out as
+  ``(rows, 128)`` bytes with ``rows`` a multiple of 32, the TPU's byte
+  tile, so no replica carries layout padding.
 """
 
 from __future__ import annotations
@@ -28,7 +30,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .topology import ClusterTopology
-from ..jax_compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,9 +88,14 @@ def coldstart_time(
 # --------------------------------------------------------------------------- functional path
 
 
+LANES = 128
+STRIPE_ALIGN = 32 * LANES  # one (32, 128) uint8 tile
+
+
 def stripe_shards(payload: bytes, n: int) -> list[np.ndarray]:
-    """Split a bundle into n equal uint8 stripes (zero-padded tail)."""
-    pad = (-len(payload)) % n
+    """Split a bundle into n equal uint8 stripes (zero-padded tail), each
+    a whole number of ``STRIPE_ALIGN``-byte tiles."""
+    pad = (-len(payload)) % (n * STRIPE_ALIGN)
     buf = np.frombuffer(payload + b"\x00" * pad, dtype=np.uint8)
     return list(buf.reshape(n, -1))
 
@@ -97,17 +103,18 @@ def stripe_shards(payload: bytes, n: int) -> list[np.ndarray]:
 def allgather_bundle(striped: jax.Array, mesh: jax.sharding.Mesh, axis: str) -> jax.Array:
     """Replicate a host-striped uint8 bundle via one all-gather over ``axis``.
 
-    ``striped`` has shape (n_stripes, stripe_len) sharded (axis, None); the
-    result is fully replicated — every device (host) holds the whole bundle.
+    ``striped`` is sharded over ``axis`` on its leading (stripe) dimension;
+    the result is fully replicated — every device (host) holds the whole
+    bundle.
     """
 
     def gather(x):
         return jax.lax.all_gather(x, axis, axis=0, tiled=True)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         gather,
         mesh=mesh,
-        in_specs=P(axis, None),
+        in_specs=P(axis),
         out_specs=P(),
         check_vma=False,
     )
@@ -118,10 +125,10 @@ def broadcast_bundle(
     payload: bytes, mesh: jax.sharding.Mesh, axis: str
 ) -> tuple[jax.Array, int]:
     """End-to-end: stripe -> place sharded -> all-gather. Returns
-    (replicated uint8 array of shape (n, stripe_len), original length)."""
+    (replicated uint8 array of shape (n, rows, 128), original length)."""
     n = mesh.shape[axis]
-    stripes = np.stack(stripe_shards(payload, n))
-    sharding = NamedSharding(mesh, P(axis, None))
+    stripes = np.stack(stripe_shards(payload, n)).reshape(n, -1, LANES)
+    sharding = NamedSharding(mesh, P(axis))
     placed = jax.device_put(stripes, sharding)
     return allgather_bundle(placed, mesh, axis), len(payload)
 

@@ -21,8 +21,9 @@ to the *whole* hot path:
   first, with device offload behind ``FleetSpec.backend``: ``"jit"``
   routes water-filling through a ``jax.jit`` float32 kernel, ``"pallas"``
   makes the tick device-resident — the have matrix, replica counts, and
-  tie-break jitter stay on the accelerator across ticks and selection +
-  water-filling run as Pallas kernels (:mod:`repro.kernels.swarm`).
+  tie-break jitter stay on the accelerator across ticks, selection runs as
+  a Pallas kernel and water-filling on the device as well
+  (:mod:`repro.kernels.swarm`).
   Float32 backends are a throughput choice, never used for goldens.
 
 Fidelity model (the documented small-N equivalence bound)
@@ -182,10 +183,9 @@ def _jax_waterfill(src, dst, up_cap, down_cap):
     Used only behind ``FleetSpec.jit`` — float32 on accelerator backends is
     a throughput choice, never a goldens path.
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
-
-    from .. import jax_compat  # new jax surface routes through the shim
 
     nf, nn = src.size, up_cap.size
     pf = 1 << max(3, (nf - 1).bit_length())
@@ -228,7 +228,7 @@ def _jax_waterfill(src, dst, up_cap, down_cap):
             )
             return lax.while_loop(cond, body, init)[0]
 
-        _JAX_FILL_CACHE[key] = jax_compat.jit(fill)
+        _JAX_FILL_CACHE[key] = jax.jit(fill)
 
     dummy = pn - 1  # zero-cap sink: padded flows freeze at 0 immediately
     s = np.full(pf, dummy, dtype=np.int32)
@@ -261,10 +261,10 @@ class FleetSpec:
     - ``"numpy"`` — the float64 reference semantics (the goldens path);
     - ``"jit"`` — water-filling through the ``jax.jit`` float32 kernel
       (spine-linked topologies still fall back to numpy);
-    - ``"pallas"`` — device-resident tick: Pallas selection + water-fill
-      kernels (``repro.kernels.swarm``), have-matrix / replica counts /
-      jitter held on device across ticks. Falls back to ``"jit"`` with a
-      warning when the installed jax has no Pallas.
+    - ``"pallas"`` — device-resident tick: Pallas selection kernel and
+      device water-fill (``repro.kernels.swarm``), have-matrix / replica counts /
+      jitter held on device across ticks. Raises where the installed jax
+      has no Pallas; it never degrades to another backend.
 
     ``None`` normalizes from the deprecated ``jit`` flag (``True`` ->
     ``"jit"``, else ``"numpy"``); after ``__post_init__`` the two fields
@@ -592,29 +592,13 @@ class FleetSwarmSim:
         self.rechoke_ticks = max(
             1, int(round(cfg.choke_interval / self.dt))
         )
-        # backend resolution: "pallas" needs the Pallas toolchain; degrade
-        # to the jit water-filling path with a warning rather than fail
-        self._backend = self.fleet_cfg.backend
-        self._dev = None
-        if self._backend == "pallas":
-            from .. import jax_compat
+        # device-resident state under backend="pallas" (an install without
+        # Pallas fails on this import), else None
+        self.device = None
+        if self.fleet_cfg.backend == "pallas":
+            from ..kernels.swarm import FleetDeviceState
 
-            if not jax_compat.HAS_PALLAS:
-                warnings.warn(
-                    "FleetSpec.backend='pallas' requested but "
-                    "jax.experimental.pallas is unavailable; "
-                    "falling back to backend='jit'",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-                self._backend = "jit"
-            else:
-                from ..kernels import swarm as swarm_kernels
-
-                self._dev = swarm_kernels.FleetDeviceState(
-                    self.jitter, self.swarm_class
-                )
-                self._waterfill_dev = swarm_kernels.fleet_waterfill
+            self.device = FleetDeviceState(self.jitter, self.swarm_class)
         # wall-clock per phase across the whole run (run.py --profile)
         self.phase_seconds = {
             "select": 0.0, "waterfill": 0.0,
@@ -659,8 +643,8 @@ class FleetSwarmSim:
             return
         self.departed[rows] = True
         self.replicas -= self.have[rows].sum(axis=0)
-        if self._dev is not None:
-            self._dev.drop_rows(rows)
+        if self.device is not None:
+            self.device.drop_rows(rows)
         if self.telemetry.enabled and self.n <= self.peer_event_limit:
             for i in rows:
                 self.telemetry.emit(
@@ -686,10 +670,10 @@ class FleetSwarmSim:
             self.cur_swarm[rows] if stream == "http"
             else self.cur_http[rows]
         )
-        if self._dev is not None:
+        if self.device is not None:
             # device path: cand mask built on the accelerator, only the
             # (k,) pick vector crosses back
-            pick = self._dev.select(
+            pick = self.device.select(
                 rows, other, stream=stream,
                 mode=self.policy.mode,
                 fallback=self.policy.http_fallback,
@@ -909,12 +893,12 @@ class FleetSwarmSim:
                     link_of = np.where(cross, 0, -1).astype(np.int64)
                     link_cap = np.array([self.spine_bps])
                 wf_t0 = perf_counter()
-                if self._dev is not None:
-                    # Pallas kernel handles spine links natively
-                    rates = self._waterfill_dev(
+                if self.device is not None:
+                    # both device paths handle spine links natively
+                    rates = self.device.waterfill(
                         fsrc, fdst, up_cap, down_cap, link_of, link_cap
                     )
-                elif self._backend == "jit" and link_of is None:
+                elif self.fleet_cfg.backend == "jit" and link_of is None:
                     rates = _jax_waterfill(fsrc, fdst, up_cap, down_cap)
                 else:
                     rates = waterfill_rates(
@@ -964,8 +948,8 @@ class FleetSwarmSim:
                     self.have[rows, pieces] = True
                     self.nhave[rows] += 1
                     np.add.at(self.replicas, pieces, 1)
-                    if self._dev is not None:
-                        self._dev.add_pieces(rows, pieces)
+                    if self.device is not None:
+                        self.device.add_pieces(rows, pieces)
                     prog[rows] -= sizes
                     self.downloaded[rows] += sizes
                     was_http_class = ~self.swarm_class[pieces]

@@ -3,12 +3,16 @@
 The data-integrity layer for device-resident bundles: after a checkpoint
 or dataset shard is broadcast over the fabric (swarm or ICI all-gather),
 each host verifies its device-resident copy WITHOUT a device->host copy of
-the payload. Fletcher-64-style dual running sums over int32 lanes —
-associative per block, so each grid step folds one VMEM tile into two
-scalar accumulators held in SMEM-like scratch. (SHA-256 stays on the host
-for wire-format compatibility with the tracker's piece table; this kernel
-covers the on-device replication fabric, where both endpoints share the
-algorithm — see DESIGN.md §6.)
+the payload. Fletcher-64-style dual running sums over 32-bit words —
+associative per block, so each grid step folds one ``(block // 128, 128)``
+VMEM tile into two scalar accumulators held in SMEM. (SHA-256 stays on the
+host for wire-format compatibility with the tracker's piece table; this
+kernel covers the on-device replication fabric, where both endpoints
+share the algorithm.)
+
+All arithmetic is int32: a word's unsigned value mod 65521 is formed from
+its two 16-bit halves (``2^16 = 15 mod 65521``), and with at most 32768
+words per block no partial sum reaches ``2^31``.
 """
 
 from __future__ import annotations
@@ -17,10 +21,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 MOD = 65521  # largest prime < 2^16 (Adler-32's modulus)
+LANES = 128
+MAX_BLOCK = 32768  # keeps every int32 partial sum below 2^31
 
 
 def _checksum_kernel(x_ref, o_ref, acc_ref, *, nblocks: int, bsz: int):
@@ -28,19 +35,25 @@ def _checksum_kernel(x_ref, o_ref, acc_ref, *, nblocks: int, bsz: int):
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[0] = jnp.int32(0)
+        acc_ref[1] = jnp.int32(0)
 
-    mod = jnp.uint32(MOD)
-    x = x_ref[...].astype(jnp.uint32)
-    s1 = jnp.sum(x % mod) % mod
+    # 8-bit inputs widen here, so byte bundles stay bytes in HBM
+    x = x_ref[...].astype(jnp.int32)
+    xm = lax.rem(lax.shift_right_logical(x, 16) * 15 + (x & 0xFFFF), MOD)
     # position-weighted sum makes the checksum order-sensitive
-    w = (jax.lax.broadcasted_iota(jnp.uint32, (bsz,), 0) + 1) % mod
-    s2 = jnp.sum((x % mod) * w % mod) % mod
+    pos = (
+        lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANES
+        + lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        + 1
+    )
+    s1 = lax.rem(jnp.sum(xm), MOD)
+    s2 = lax.rem(jnp.sum(lax.rem(xm * pos, MOD)), MOD)
     prev1 = acc_ref[0]
     prev2 = acc_ref[1]
     # fold block: s2_total += s1_prev * bsz + s2_block  (Fletcher composition)
-    acc_ref[0] = (prev1 + s1) % mod
-    acc_ref[1] = (prev2 + (prev1 * jnp.uint32(bsz % MOD)) % mod + s2) % mod
+    acc_ref[0] = lax.rem(prev1 + s1, MOD)
+    acc_ref[1] = lax.rem(prev2 + lax.rem(prev1 * (bsz % MOD), MOD) + s2, MOD)
 
     @pl.when(i == nblocks - 1)
     def _emit():
@@ -48,19 +61,23 @@ def _checksum_kernel(x_ref, o_ref, acc_ref, *, nblocks: int, bsz: int):
         o_ref[1] = acc_ref[1]
 
 
-def checksum_u32(x: jax.Array, *, block: int = 2048, interpret: bool = True):
-    """x: flat uint32/int32 vector (padded to block multiple by ops.py).
+def checksum_words(x: jax.Array, *, block: int, interpret: bool):
+    """x: ``(rows, 128)`` words (int32, or 8-bit values widened in the
+    kernel), ``rows * 128`` a multiple of ``block`` (``ops.py`` pads).
     Returns (2,) uint32: (sum, weighted-sum) both mod 65521."""
-    n = x.shape[0]
-    assert n % block == 0
-    nb = n // block
+    rows, lanes = x.shape
+    assert lanes == LANES and block % LANES == 0 and block <= MAX_BLOCK
+    br = block // LANES
+    assert rows % br == 0
+    nb = rows // br
     kernel = functools.partial(_checksum_kernel, nblocks=nb, bsz=block)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((2,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((2,), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((2,), jnp.uint32)],
+        in_specs=[pl.BlockSpec((br, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
         interpret=interpret,
-    )(x.astype(jnp.uint32))
+    )(x)
+    return out.astype(jnp.uint32)

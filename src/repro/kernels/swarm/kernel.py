@@ -17,19 +17,25 @@ parity with the numpy engine *index-exact*, not a tolerance band.
 ``lax.while_loop`` (all unfrozen flows grow equally until a node or
 spine-link constraint saturates; flows through it freeze; repeat — at
 least one constraint binds per round, so ``2*nodes + links + 2`` rounds
-bound the loop and the early-exit fires long before). Per-round
-segment-sums (active flows per node/link) and per-flow saturation gathers
-run in flow tiles of ``block`` using one-hot matmuls — MXU-shaped, and
-exact even under bfloat16 MXU inputs because every operand is 0/1 or a
-small integer count with float32 accumulation. ``segments="scatter"``
-swaps in ``.at[].add`` / direct gathers for interpret-mode CI speed; both
-produce bit-identical float32 results (all segment values are exact
+bound the loop and the early-exit fires long before). Two paths run it:
+
+- the Pallas kernel keeps the whole flow table in VMEM and forms the
+  per-round segment sums (active flows per node/link) and per-flow
+  saturation gathers in flow tiles of ``block`` as one-hot matmuls —
+  MXU-shaped, and exact even under bfloat16 MXU inputs because every
+  operand is 0/1 or a small integer count with float32 accumulation. Its
+  one-hot tiles grow with the node count, so it serves tables whose
+  footprint (:func:`waterfill_vmem_bytes`) fits the VMEM budget;
+- :func:`waterfill_xla` runs the same rounds as plain XLA ops in device
+  memory (scatter-add segment sums, direct gathers) for larger tables.
+
+Both produce bit-identical float32 results (all segment values are exact
 integers, gathers touch one element), pinned by the parity suite.
 
 Exactness contract: the bit-for-bit oracle is ``ref.waterfill_jnp_ref``
-(a plain unpadded jnp loop compiled through the same XLA pipeline), which
-pins everything the kernel adds — tiling, padding, the dummy link slot,
-one-hot segment math. The numpy transliteration ``ref.waterfill_f32_ref``
+(:func:`waterfill_xla` on the unpadded table), which pins everything the
+device paths add — tiling, padding, the dummy link slot, one-hot segment
+math. The numpy transliteration ``ref.waterfill_f32_ref``
 is ulp-close but *not* bitwise: XLA:CPU unconditionally contracts the
 ``alloc + count * delta`` multiply-adds into single-rounded FMAs
 (``lax.optimization_barrier`` does not reach LLVM's codegen), while numpy
@@ -50,8 +56,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from ... import jax_compat
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # plain-float inf stays a weakly-typed literal (folds to float32 in
 # kernel bodies without becoming a captured traced constant)
@@ -65,7 +71,6 @@ def _rarest_argmin_kernel(
     cand_ref, avail_ref, jit_ref, pick_ref, a_min, j_min, i_min,
     *, npb: int, bp: int
 ):
-    pl, _ = jax_compat.pallas_modules()
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -75,15 +80,18 @@ def _rarest_argmin_kernel(
         i_min[...] = jnp.full_like(i_min, -1)
 
     c = cand_ref[...]
-    # stage 1: masked availability minimum per row within this piece tile
-    a = jnp.where(c, avail_ref[...][None, :], F32_INF)
-    tile_a = a.min(axis=1)
+    # stage 1: masked availability minimum per row within this piece tile;
+    # every per-row value below is a (rows, 1) column
+    a = jnp.where(c, avail_ref[...], F32_INF)
+    tile_a = a.min(axis=1, keepdims=True)
     # stage 2: jitter among this tile's minimal-availability candidates
     # (the `c &` guard keeps inf==inf rows of all-masked tiles out)
-    jm = jnp.where(c & (a == tile_a[:, None]), jit_ref[...], F32_INF)
-    tile_j = jm.min(axis=1)
-    # argmin returns the first occurrence -> lowest piece index in the tile
-    tile_i = jnp.argmin(jm, axis=1).astype(jnp.int32) + jnp.int32(j * bp)
+    jm = jnp.where(c & (a == tile_a), jit_ref[...], F32_INF)
+    tile_j = jm.min(axis=1, keepdims=True)
+    # first occurrence of the minimum -> lowest piece index in the tile
+    col = lax.broadcasted_iota(jnp.int32, jm.shape, 1)
+    tile_i = jnp.where(jm == tile_j, col, bp).min(axis=1, keepdims=True)
+    tile_i = tile_i + j * bp
     prev_a = a_min[...]
     prev_j = j_min[...]
     # strictly-less merge: on exact (avail, jitter) ties the earlier tile
@@ -105,147 +113,166 @@ def rarest_argmin_call(
     *,
     block_rows: int = 128,
     block_pieces: int = 256,
-    interpret=None,
+    interpret: bool,
 ):
     """``(k, P)`` bool candidates + ``(P,)`` float32 availability + ``(k, P)``
     float32 jitter -> ``(k,)`` int32 picks (``-1`` = no candidate).
 
     Shapes must already be multiples of the block sizes (``ops.py`` pads);
-    traceable, so it composes under ``jax.jit``.
+    traceable, so it composes under ``jax.jit``. Every block is 2-D:
+    availability enters as one ``(1, P)`` row and picks leave as a
+    ``(k, 1)`` column, so each block matches the TPU's native tiling.
     """
     k, P = cand.shape
     assert k % block_rows == 0 and P % block_pieces == 0
     nkb, npb = k // block_rows, P // block_pieces
-    pl, pltpu = jax_compat.pallas_modules()
     kernel = functools.partial(
         _rarest_argmin_kernel, npb=npb, bp=block_pieces
     )
-    return jax_compat.pallas_call(
+    col = (block_rows, 1)
+    picks = pl.pallas_call(
         kernel,
         grid=(nkb, npb),
         in_specs=[
             pl.BlockSpec((block_rows, block_pieces), lambda i, j: (i, j)),
-            pl.BlockSpec((block_pieces,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_pieces), lambda i, j: (0, j)),
             pl.BlockSpec((block_rows, block_pieces), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((k,), jnp.int32),
+        out_specs=pl.BlockSpec(col, lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, 1), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.int32),
+            pltpu.VMEM(col, jnp.float32),
+            pltpu.VMEM(col, jnp.float32),
+            pltpu.VMEM(col, jnp.int32),
         ],
         interpret=interpret,
-    )(cand, avail, jitter)
+    )(cand, avail.reshape(1, P), jitter)
+    return picks[:, 0]
 
 
 # --------------------------------------------------------------------------- water-filling
 
 
+def _fill_round(counts, alloc, caps):
+    """One progressive-filling round at the constraints, shared by the
+    kernel and :func:`waterfill_xla` so both run the same float ops.
+
+    ``counts`` / ``alloc`` / ``caps`` are ``(up, down, link)`` triples of
+    active-flow counts, allocated rate and capacity per constraint. Returns
+    the round's growth ``delta``, whether it was finite, the new
+    allocations, and the 0/1 float saturation mask of every constraint.
+    """
+    ds = [
+        jnp.where(n > 0, (c - a) / n, F32_INF)
+        for n, a, c in zip(counts, alloc, caps)
+    ]
+    delta = jnp.minimum(jnp.minimum(ds[0].min(), ds[1].min()), ds[2].min())
+    # a non-finite delta means no active flow touches any finite capacity;
+    # delta = 0 then makes every update an exact no-op and the loop exits
+    ok = delta < F32_INF
+    delta = jnp.where(ok, jnp.maximum(delta, jnp.float32(0.0)), 0.0)
+    alloc = tuple(a + n * delta for a, n in zip(alloc, counts))
+    tol = delta + jnp.float32(1e-6)
+    sats = tuple(
+        ((d <= tol) & (n > 0)).astype(jnp.float32)
+        for d, n in zip(ds, counts)
+    )
+    return delta, ok, alloc, sats
+
+
 def _waterfill_kernel(
     src_ref, dst_ref, lnk_ref, up_ref, dn_ref, lcap_ref,
-    rate_ref, iters_ref,
-    *, n_iter: int, block: int, pn: int, pnl: int, segments: str
+    rate_ref, rounds_ref, frozen_ref, *, n_iter: int
 ):
-    src = src_ref[...]
-    dst = dst_ref[...]
-    lnk = lnk_ref[...]
-    up = up_ref[...]
-    dn = dn_ref[...]
-    lcap = lcap_ref[...]
-    pf = src.shape[0]
-    ntiles = pf // block
+    nt, block = src_ref.shape
+    idx_refs = (src_ref, dst_ref, lnk_ref)
+    caps = (up_ref[...], dn_ref[...], lcap_ref[...])  # (1, width) rows
+    widths = tuple(c.shape[1] for c in caps)
 
-    def tile(vec, t):
-        return lax.dynamic_slice(vec, (t * block,), (block,))
+    def tile(ref, t):
+        return ref[pl.ds(t, 1), :]  # one (1, block) row of flows
 
-    def onehot(idx_tile, width):
-        iota = lax.broadcasted_iota(jnp.int32, (block, width), 1)
-        return (idx_tile[:, None] == iota).astype(jnp.float32)
+    def onehot(idx, width):
+        # (1, block) indices -> (width, block) 0/1, flows along lanes;
+        # -1 padding matches no row
+        rows = lax.broadcasted_iota(jnp.int32, (width, block), 0)
+        return (rows == idx).astype(jnp.float32)
 
-    if segments == "onehot":
+    def seg_sum(w, idx, width):  # (1, block) weights -> (1, width) sums
+        return lax.dot_general(
+            w, onehot(idx, width), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-        def counts(act):
-            def body(t, accs):
-                nu, nd, nl = accs
-                w = tile(act, t)
-                nu = nu + w @ onehot(tile(src, t), pn)
-                nd = nd + w @ onehot(tile(dst, t), pn)
-                nl = nl + w @ onehot(tile(lnk, t), pnl)
-                return (nu, nd, nl)
+    def gather(v, idx, width):  # (1, width) values -> (1, block)
+        return jnp.dot(
+            v, onehot(idx, width), preferred_element_type=jnp.float32
+        )
 
-            zn = jnp.zeros(pn, jnp.float32)
-            return lax.fori_loop(
-                0, ntiles, body, (zn, zn, jnp.zeros(pnl, jnp.float32))
-            )
-
-        def flow_hits(sat_u, sat_d, sat_l):
-            def body(t, out):
-                hit = (
-                    onehot(tile(src, t), pn) @ sat_u
-                    + onehot(tile(dst, t), pn) @ sat_d
-                    + onehot(tile(lnk, t), pnl) @ sat_l
-                )
-                return lax.dynamic_update_slice(out, hit > 0, (t * block,))
-
-            return lax.fori_loop(0, ntiles, body, jnp.zeros(pf, bool))
-
-    else:  # "scatter": interpret-mode fast path, bit-identical results
-
-        def counts(act):
-            safe_s = jnp.where(src < 0, pn - 1, src)  # -1 pads carry act=0
-            safe_d = jnp.where(dst < 0, pn - 1, dst)
-            nu = jnp.zeros(pn, jnp.float32).at[safe_s].add(act)
-            nd = jnp.zeros(pn, jnp.float32).at[safe_d].add(act)
-            nl = jnp.zeros(pnl, jnp.float32).at[lnk].add(act)
-            return (nu, nd, nl)
-
-        def flow_hits(sat_u, sat_d, sat_l):
-            safe_s = jnp.where(src < 0, pn - 1, src)
-            safe_d = jnp.where(dst < 0, pn - 1, dst)
-            return (sat_u[safe_s] + sat_d[safe_d] + sat_l[lnk]) > 0
+    rate_ref[...] = jnp.zeros_like(rate_ref)
+    # padded flows (src = -1) start frozen at rate 0
+    frozen_ref[...] = (src_ref[...] < 0).astype(jnp.float32)
 
     def body(state):
-        rate, frozen, up_a, dn_a, lk_a, it, done = state
-        act = (~frozen).astype(jnp.float32)
-        n_up, n_dn, n_lk = counts(act)
-        du = jnp.where(n_up > 0, (up - up_a) / n_up, F32_INF)
-        dd = jnp.where(n_dn > 0, (dn - dn_a) / n_dn, F32_INF)
-        dl = jnp.where(n_lk > 0, (lcap - lk_a) / n_lk, F32_INF)
-        delta = jnp.minimum(jnp.minimum(du.min(), dd.min()), dl.min())
-        ok = jnp.isfinite(delta)
-        # a non-finite delta means no active flow touches any finite
-        # capacity; the reference breaks before updating -- delta = 0 makes
-        # every update below an exact no-op and `done` exits the loop
-        delta = jnp.where(ok, jnp.maximum(delta, jnp.float32(0.0)), 0.0)
-        rate = rate + act * delta
-        up_a = up_a + n_up * delta
-        dn_a = dn_a + n_dn * delta
-        lk_a = lk_a + n_lk * delta
-        tol = delta + jnp.float32(1e-6)
-        sat_u = ((du <= tol) & (n_up > 0)).astype(jnp.float32)
-        sat_d = ((dd <= tol) & (n_dn > 0)).astype(jnp.float32)
-        sat_l = ((dl <= tol) & (n_lk > 0)).astype(jnp.float32)
-        newly = (~frozen) & flow_hits(sat_u, sat_d, sat_l)
-        done = ~(ok & newly.any())
-        return (rate, frozen | newly, up_a, dn_a, lk_a, it + 1, done)
+        it, _, alloc = state
+
+        def count(t, acc):
+            act = 1.0 - tile(frozen_ref, t)
+            return tuple(
+                a + seg_sum(act, tile(r, t), w)
+                for a, r, w in zip(acc, idx_refs, widths)
+            )
+
+        zeros = tuple(jnp.zeros((1, w), jnp.float32) for w in widths)
+        counts = lax.fori_loop(0, nt, count, zeros)
+        delta, ok, alloc, sats = _fill_round(counts, alloc, caps)
+
+        def advance(t, carry):
+            any_new, any_live = carry
+            fr = tile(frozen_ref, t)
+            act = 1.0 - fr
+            rate_ref[pl.ds(t, 1), :] = tile(rate_ref, t) + act * delta
+            hit = sum(
+                gather(s, tile(r, t), w)
+                for s, r, w in zip(sats, idx_refs, widths)
+            )
+            newly = jnp.where(hit > 0, act, 0.0)
+            fr = fr + newly
+            frozen_ref[pl.ds(t, 1), :] = fr
+            return (
+                jnp.maximum(any_new, newly.max()),
+                jnp.maximum(any_live, (1.0 - fr).max()),
+            )
+
+        any_new, any_live = lax.fori_loop(
+            0, nt, advance, (jnp.float32(0.0), jnp.float32(0.0))
+        )
+        stop = jnp.logical_not(ok & (any_new > 0)) | (any_live == 0)
+        return it + 1, stop.astype(jnp.int32), alloc
 
     def cond(state):
-        _, frozen, _, _, _, it, done = state
-        return (~done) & (it < n_iter) & (~frozen.all())
+        it, stop, _ = state
+        return (stop == 0) & (it < n_iter)
 
-    init = (
-        jnp.zeros(pf, jnp.float32),
-        src < 0,  # padded flows pre-frozen at rate 0
-        jnp.zeros(pn, jnp.float32),
-        jnp.zeros(pn, jnp.float32),
-        jnp.zeros(pnl, jnp.float32),
-        jnp.int32(0),
-        jnp.asarray(False),
-    )
-    out = lax.while_loop(cond, body, init)
-    rate_ref[...] = out[0]
-    iters_ref[0] = out[5]
+    alloc0 = tuple(jnp.zeros((1, w), jnp.float32) for w in widths)
+    it, _, _ = lax.while_loop(cond, body, (jnp.int32(0), jnp.int32(0), alloc0))
+    rounds_ref[0] = it
+
+
+def waterfill_vmem_bytes(pf: int, pn: int, pnl: int, block: int) -> int:
+    """Upper bound on the water-fill kernel's VMEM footprint.
+
+    The kernel holds the whole flow table in VMEM: three index rows, the
+    rates and the frozen mask (each ``pf`` 32-bit words, inputs and
+    outputs double-buffered), the node and link rows (padded to 8
+    sublanes), and per flow tile the one-hot ``(width, block)`` float32
+    operands with their int32 iotas. Computed from shapes alone, so
+    ``fleet_waterfill`` can choose its path before anything compiles.
+    """
+    flows = (2 * 4 + 1) * 4 * pf
+    rows = 16 * 8 * 4 * (2 * pn + pnl)
+    tiles = 3 * 2 * 4 * block * (2 * pn + pnl)
+    return flows + rows + tiles
 
 
 def waterfill_call(
@@ -257,34 +284,79 @@ def waterfill_call(
     link_cap: jax.Array,
     *,
     n_iter: int,
-    block: int = 256,
-    segments: str = "onehot",
-    interpret=None,
+    block: int,
+    vmem_limit_bytes: int,
+    interpret: bool,
 ):
     """Padded flow table -> ``((pf,) float32 rates, (1,) int32 rounds)``.
 
     ``src``/``dst``/``lnk`` are int32 node/link indices per flow (``-1``
     src/dst = padding; ``lnk`` already maps unlinked flows to the dummy
     slot). The fixed point is sequential, so the kernel is single-program
-    (no pallas grid) and tiles the flow axis internally; see the module
-    docstring for the ``segments`` modes.
+    (no pallas grid): the whole table sits in VMEM as ``(pf // block,
+    block)`` rows, and each round walks the rows, forming per-node and
+    per-link segment sums and saturation gathers as one-hot matmuls.
     """
-    assert segments in ("onehot", "scatter")
     pf = src.shape[0]
     assert pf % block == 0
-    kernel = functools.partial(
-        _waterfill_kernel,
-        n_iter=n_iter,
-        block=block,
-        pn=up_cap.shape[0],
-        pnl=link_cap.shape[0],
-        segments=segments,
-    )
-    return jax_compat.pallas_call(
-        kernel,
+    nt = pf // block
+    flows = lambda x: x.reshape(nt, block)  # noqa: E731
+    row = lambda x: x.reshape(1, x.shape[0])  # noqa: E731
+    rate, rounds = pl.pallas_call(
+        functools.partial(_waterfill_kernel, n_iter=n_iter),
         out_shape=(
-            jax.ShapeDtypeStruct((pf,), jnp.float32),
+            jax.ShapeDtypeStruct((nt, block), jnp.float32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ),
+        out_specs=(
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ),
+        scratch_shapes=[pltpu.VMEM((nt, block), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes
+        ),
         interpret=interpret,
-    )(src, dst, lnk, up_cap, down_cap, link_cap)
+    )(flows(src), flows(dst), flows(lnk),
+      row(up_cap), row(down_cap), row(link_cap))
+    return rate.reshape(pf), rounds
+
+
+def waterfill_xla(src, dst, lnk, up_cap, down_cap, link_cap, *, n_iter: int):
+    """The kernel's fixed point as plain XLA ops: scatter-add segment
+    sums and direct gathers, over 1-D arrays in device memory, with no
+    VMEM bound. Takes the kernel's padded table (``-1`` flows start
+    frozen) or an unpadded one; returns ``((nf,) rates, rounds)``.
+    """
+    caps = (up_cap, down_cap, link_cap)
+    idx = (src, dst, lnk)
+
+    def body(state):
+        rate, frozen, alloc, it, _ = state
+        act = (~frozen).astype(jnp.float32)
+        # -1 padding wraps to the last slot, where it adds 0
+        counts = tuple(
+            jnp.zeros(c.shape[0], jnp.float32).at[i].add(act)
+            for c, i in zip(caps, idx)
+        )
+        delta, ok, alloc, sats = _fill_round(counts, alloc, caps)
+        rate = rate + act * delta
+        hit = sats[0][src] + sats[1][dst] + sats[2][lnk]
+        newly = (~frozen) & (hit > 0)
+        frozen = frozen | newly
+        stop = ~(ok & newly.any()) | frozen.all()
+        return rate, frozen, alloc, it + 1, stop
+
+    def cond(state):
+        *_, it, stop = state
+        return (~stop) & (it < n_iter)
+
+    init = (
+        jnp.zeros(src.shape[0], jnp.float32),
+        src < 0,
+        tuple(jnp.zeros(c.shape[0], jnp.float32) for c in caps),
+        jnp.int32(0),
+        jnp.asarray(False),
+    )
+    rate, _, _, it, _ = lax.while_loop(cond, body, init)
+    return rate, it.reshape(1)

@@ -19,24 +19,35 @@ Three layers, mirroring the checksum/attention packages:
   made explicit below), so variable-size updates reuse a handful of
   power-of-two traces.
 
-Everything resolves ``interpret`` through :mod:`repro.jax_compat` so the
-same code path is CPU-testable in CI and compiled on TPU backends.
+``interpret=None`` resolves per platform
+(:func:`repro.accel.pallas_interpret`): compiled on a TPU, the Pallas
+interpreter on the CPU test backend.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ... import jax_compat
+from ...accel import pallas_interpret
 from ...core.piece_selection import MAX_EXACT_AVAILABILITY
-from .kernel import rarest_argmin_call, waterfill_call
+from .kernel import (
+    rarest_argmin_call,
+    waterfill_call,
+    waterfill_vmem_bytes,
+    waterfill_xla,
+)
 
 BLOCK_ROWS = 128
 BLOCK_PIECES = 256
-BLOCK_FLOWS = 256
+BLOCK_FLOWS = 128
+# scoped-VMEM budget of the water-fill kernel; larger tables run the XLA
+# fixed point (v5e has 128 MiB of VMEM, 16 MiB scoped by default)
+WATERFILL_VMEM_LIMIT = 16 << 20
 
 
 def _next_pow2(x: int, lo: int = 0) -> int:
@@ -44,25 +55,22 @@ def _next_pow2(x: int, lo: int = 0) -> int:
 
 
 def _resolve_interpret(interpret) -> bool:
-    if interpret is None:
-        return jax_compat.default_pallas_interpret()
-    return bool(interpret)
+    return pallas_interpret() if interpret is None else bool(interpret)
+
+
+def _piece_block(P: int) -> int:
+    return min(BLOCK_PIECES, _next_pow2(P, 7))
 
 
 # --------------------------------------------------------------------------- rarest-argmin
 
 
 @functools.lru_cache(maxsize=None)
-def _rarest_jit(bk: int, bp: int, interpret: bool):
-    import jax.numpy as jnp  # noqa: F401  (deferred: numpy engine stays jax-free)
-
-    def fn(cand, avail, jitter):
-        return rarest_argmin_call(
-            cand, avail, jitter,
-            block_rows=bk, block_pieces=bp, interpret=interpret,
-        )
-
-    return jax_compat.jit(fn)
+def _rarest_jit(bp: int, interpret: bool):
+    return jax.jit(functools.partial(
+        rarest_argmin_call,
+        block_rows=BLOCK_ROWS, block_pieces=bp, interpret=interpret,
+    ))
 
 
 def rarest_argmin(
@@ -82,10 +90,8 @@ def rarest_argmin(
     assert int(avail.max(initial=0)) < MAX_EXACT_AVAILABILITY, (
         "replica counts no longer exact in float32 — fleet too large"
     )
-    interpret = _resolve_interpret(interpret)
-    bk = min(BLOCK_ROWS, _next_pow2(k, 3))
-    bp = min(BLOCK_PIECES, _next_pow2(P, 3))
-    kp = -(-k // bk) * bk
+    bp = _piece_block(P)
+    kp = -(-k // BLOCK_ROWS) * BLOCK_ROWS
     Pp = -(-P // bp) * bp
     candp = np.zeros((kp, Pp), dtype=bool)
     candp[:k, :P] = cand
@@ -93,23 +99,85 @@ def rarest_argmin(
     availp[:P] = avail
     jitp = np.zeros((kp, Pp), dtype=np.float32)
     jitp[:k, :P] = jitter
-    out = _rarest_jit(bk, bp, interpret)(candp, availp, jitp)
+    out = _rarest_jit(bp, _resolve_interpret(interpret))(
+        candp, availp, jitp
+    )
     return np.asarray(out)[:k].astype(np.int64)
 
 
 # --------------------------------------------------------------------------- water-filling
 
 
-@functools.lru_cache(maxsize=None)
-def _waterfill_jit(n_iter: int, block: int, segments: str, interpret: bool):
-    def fn(s, d, lk, up, dn, lc):
-        return waterfill_call(
-            s, d, lk, up, dn, lc,
-            n_iter=n_iter, block=block, segments=segments,
-            interpret=interpret,
-        )
+class WaterfillPlan(NamedTuple):
+    """Padded shapes of one water-fill call and the path that runs it."""
 
-    return jax_compat.jit(fn)
+    pf: int  # flows
+    pn: int  # nodes
+    pnl: int  # spine links + the dummy slot
+    impl: str  # "pallas" (VMEM kernel) or "xla" (fixed point in HBM)
+
+
+def waterfill_plan(nf: int, nn: int, nl: int) -> WaterfillPlan:
+    """Pad to powers of two (at least one lane width, so the few shapes a
+    run sees compile once each) and pick the implementation from the
+    shapes alone: the Pallas kernel when its whole table fits
+    :data:`WATERFILL_VMEM_LIMIT`, else the XLA fixed point."""
+    pf, pn, pnl = (_next_pow2(x, 7) for x in (nf, nn, nl + 1))
+    fits = (
+        waterfill_vmem_bytes(pf, pn, pnl, BLOCK_FLOWS) <= WATERFILL_VMEM_LIMIT
+    )
+    return WaterfillPlan(pf, pn, pnl, "pallas" if fits else "xla")
+
+
+@functools.lru_cache(maxsize=None)
+def _waterfill_jit(n_iter: int, impl: str, interpret: bool):
+    if impl == "pallas":
+        fn = functools.partial(
+            waterfill_call, n_iter=n_iter, block=BLOCK_FLOWS,
+            vmem_limit_bytes=WATERFILL_VMEM_LIMIT, interpret=interpret,
+        )
+    else:
+        fn = functools.partial(waterfill_xla, n_iter=n_iter)
+    return jax.jit(fn)
+
+
+def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
+               interpret):
+    """Pad, run on the device, unpad: ``(rates, rounds, plan)``."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    nf = src.size
+    nn = np.asarray(up_cap).size
+    nl = 0
+    if link_of is not None and link_cap is not None:
+        link_of = np.asarray(link_of, dtype=np.int64)
+        if (link_of >= 0).any():
+            nl = np.asarray(link_cap).size
+    plan = waterfill_plan(nf, nn, nl)
+    if impl is not None:
+        plan = plan._replace(impl=impl)
+    n_iter = 2 * nn + nl + 2  # real constraint count bounds the fixed point
+
+    s = np.full(plan.pf, -1, dtype=np.int32)
+    d = np.full(plan.pf, -1, dtype=np.int32)
+    s[:nf] = src
+    d[:nf] = dst
+    lk = np.full(plan.pf, nl, dtype=np.int32)  # dummy slot (also for padding)
+    if nl:
+        lk[:nf] = np.where(link_of >= 0, link_of, nl)
+    up = np.zeros(plan.pn, dtype=np.float32)
+    dn = np.zeros(plan.pn, dtype=np.float32)
+    up[:nn] = up_cap
+    dn[:nn] = down_cap
+    lc = np.zeros(plan.pnl, dtype=np.float32)
+    lc[nl] = np.inf
+    if nl:
+        lc[:nl] = link_cap
+    rate, rounds = _waterfill_jit(n_iter, plan.impl, _resolve_interpret(
+        interpret))(s, d, lk, up, dn, lc)
+    # unpad on the host: a device-side slice would compile once per nf
+    rate = np.asarray(rate, dtype=np.float64)[:nf]
+    return rate, int(np.asarray(rounds)[0]), plan
 
 
 def fleet_waterfill(
@@ -120,60 +188,20 @@ def fleet_waterfill(
     link_of: Optional[np.ndarray] = None,
     link_cap: Optional[np.ndarray] = None,
     *,
-    segments: Optional[str] = None,
+    impl: Optional[str] = None,
     interpret=None,
-    block: int = BLOCK_FLOWS,
 ) -> np.ndarray:
-    """Kernel-backed :func:`~repro.core.fleet.waterfill_rates` (float32;
-    spine links supported). Bit-identical to ``ref.waterfill_f32_ref``;
+    """Device-backed :func:`~repro.core.fleet.waterfill_rates` (float32;
+    spine links supported). Bit-identical to ``ref.waterfill_jnp_ref``;
     within a band of the float64 goldens path.
 
-    ``segments=None`` picks ``"scatter"`` in interpret mode (CPU CI speed)
-    and ``"onehot"`` (MXU tiles) when compiling — the two are bit-identical
-    (integer segment sums, one-hot gathers).
+    ``impl=None`` takes :func:`waterfill_plan`'s choice; ``"pallas"`` or
+    ``"xla"`` forces one path (the parity tests run both).
     """
-    import jax.numpy as jnp  # deferred: numpy engine stays jax-free
-
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    nf = src.size
-    if nf == 0:
+    if np.asarray(src).size == 0:
         return np.zeros(0, dtype=np.float64)
-    interpret = _resolve_interpret(interpret)
-    if segments is None:
-        segments = "scatter" if interpret else "onehot"
-    nn = np.asarray(up_cap).size
-    nl = 0
-    if link_of is not None and link_cap is not None:
-        link_of = np.asarray(link_of, dtype=np.int64)
-        if (link_of >= 0).any():
-            nl = np.asarray(link_cap).size
-    pf = _next_pow2(nf, 3)
-    block = min(block, pf)
-    pn = _next_pow2(nn, 3)
-    pnl = _next_pow2(nl + 1)
-    n_iter = 2 * nn + nl + 2  # real constraint count bounds the fixed point
-
-    s = np.full(pf, -1, dtype=np.int32)
-    d = np.full(pf, -1, dtype=np.int32)
-    s[:nf] = src
-    d[:nf] = dst
-    lk = np.full(pf, nl, dtype=np.int32)  # dummy slot (also for padding)
-    if nl:
-        lk[:nf] = np.where(link_of >= 0, link_of, nl)
-    up = np.zeros(pn, dtype=np.float32)
-    dn = np.zeros(pn, dtype=np.float32)
-    up[:nn] = up_cap
-    dn[:nn] = down_cap
-    lc = np.zeros(pnl, dtype=np.float32)
-    lc[nl] = np.inf
-    if nl:
-        lc[:nl] = link_cap
-    rate, _ = _waterfill_jit(n_iter, block, segments, interpret)(
-        jnp.asarray(s), jnp.asarray(d), jnp.asarray(lk),
-        jnp.asarray(up), jnp.asarray(dn), jnp.asarray(lc),
-    )
-    return np.asarray(rate[:nf], dtype=np.float64)
+    return _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
+                      interpret)[0]
 
 
 # --------------------------------------------------------------------------- device state
@@ -181,11 +209,9 @@ def fleet_waterfill(
 
 @functools.lru_cache(maxsize=None)
 def _select_jit(
-    stream_http: bool, http_first: bool, fallback: bool,
-    bk: int, bp: int, interpret: bool,
+    stream_http: bool, http_first: bool, fallback: bool, bp: int,
+    interpret: bool,
 ):
-    import jax.numpy as jnp
-
     def fn(have, jitter, repl, swarm_class, rows, other):
         _, P = have.shape
         miss = ~have[rows]  # (k, P) — built and consumed on device
@@ -204,57 +230,48 @@ def _select_jit(
         # a peer's two streams exclude each other's current piece
         pid = jnp.arange(P, dtype=other.dtype)[None, :]
         cand = cand & ~((other[:, None] >= 0) & (pid == other[:, None]))
-        k = rows.shape[0]
-        kp = -(-k // bk) * bk
-        Pp = -(-P // bp) * bp
-        cand = jnp.pad(cand, ((0, kp - k), (0, Pp - P)))
-        avail = jnp.pad(repl.astype(jnp.float32), (0, Pp - P))
-        jit_rows = jnp.pad(jitter[rows], ((0, kp - k), (0, Pp - P)))
+        pad = (0, -(-P // bp) * bp - P)
         return rarest_argmin_call(
-            cand, avail, jit_rows,
-            block_rows=bk, block_pieces=bp, interpret=interpret,
+            jnp.pad(cand, ((0, 0), pad)),
+            jnp.pad(repl.astype(jnp.float32), pad),
+            jnp.pad(jitter[rows], ((0, 0), pad)),
+            block_rows=BLOCK_ROWS, block_pieces=bp, interpret=interpret,
         )
 
-    return jax_compat.jit(fn)
+    return jax.jit(fn)
 
 
-@functools.lru_cache(maxsize=None)
-def _add_pieces_jit():
-    def fn(have, repl, rows, pieces):
-        # out-of-bounds padding indices are dropped, so one trace serves
-        # every power-of-two batch size
-        have = have.at[rows, pieces].set(True, mode="drop")
-        repl = repl.at[pieces].add(1, mode="drop")
-        return have, repl
-
-    return jax_compat.jit(fn)
+@jax.jit
+def _add_pieces(have, repl, rows, pieces):
+    # out-of-bounds padding indices are dropped, so one trace serves
+    # every power-of-two batch size
+    have = have.at[rows, pieces].set(True, mode="drop")
+    repl = repl.at[pieces].add(1, mode="drop")
+    return have, repl
 
 
-@functools.lru_cache(maxsize=None)
-def _drop_rows_jit():
-    def fn(have, repl, rows):
-        got = have.at[rows].get(mode="fill", fill_value=False)
-        return repl - got.sum(axis=0).astype(repl.dtype)
-
-    return jax_compat.jit(fn)
+@jax.jit
+def _drop_rows(have, repl, rows):
+    got = have.at[rows].get(mode="fill", fill_value=False)
+    return repl - got.sum(axis=0).astype(repl.dtype)
 
 
 class FleetDeviceState:
-    """Device-resident selection state for ``FleetSpec.backend="pallas"``.
+    """Device-resident tick state for ``FleetSpec.backend="pallas"``.
 
     Holds the have-matrix, fixed jitter, replica counts, and the static
     swarm-routing class on device across ticks. The engine keeps its numpy
     mirrors for scalar control flow (leech masks, host-RNG source
     sampling); the ``O(n * P)`` candidate-mask + argmin traffic — the
     fleet tick's dominant term — happens here, and only ``(k,)`` pick
-    vectors cross back per call.
+    vectors cross back per call. Water-filling runs on the device too;
+    ``waterfill_runs`` counts the calls per implementation, and
+    ``peak_flows`` / ``rounds`` record the largest flow table and the
+    fixed-point rounds summed over the run.
     """
 
     def __init__(self, jitter: np.ndarray, swarm_class: np.ndarray,
                  *, interpret=None) -> None:
-        import jax.numpy as jnp
-
-        self._jnp = jnp
         n, P = jitter.shape
         assert n < MAX_EXACT_AVAILABILITY, (
             "replica counts no longer exact in float32 — fleet too large"
@@ -265,8 +282,10 @@ class FleetDeviceState:
         self.jitter = jnp.asarray(jitter, dtype=jnp.float32)
         self.repl = jnp.zeros(P, dtype=jnp.int32)
         self.swarm_class = jnp.asarray(swarm_class, dtype=bool)
-        self.bk = min(BLOCK_ROWS, _next_pow2(n, 3))
-        self.bp = min(BLOCK_PIECES, _next_pow2(P, 3))
+        self.bp = _piece_block(P)
+        self.waterfill_runs = {"pallas": 0, "xla": 0}
+        self.peak_flows = 0
+        self.rounds = 0
 
     def select(self, rows: np.ndarray, other: np.ndarray, *,
                stream: str, mode: str, fallback: bool) -> np.ndarray:
@@ -275,43 +294,50 @@ class FleetDeviceState:
         Semantics mirror ``FleetSwarmSim._select`` exactly (index-exact
         parity is pinned by the engine-equivalence test).
         """
-        jnp = self._jnp
         k = rows.size
-        kp = _next_pow2(k, 3)  # pad row batches to bound retraces
+        kp = _next_pow2(k, 7)  # whole row tiles; pow2 bounds retraces
         rows_p = np.zeros(kp, dtype=np.int32)
         rows_p[:k] = rows
         other_p = np.full(kp, -1, dtype=np.int32)
         other_p[:k] = other
         fn = _select_jit(
             stream == "http", mode == "http_first", bool(fallback),
-            self.bk, self.bp, self.interpret,
+            self.bp, self.interpret,
         )
         out = fn(
             self.have, self.jitter, self.repl, self.swarm_class,
-            jnp.asarray(rows_p), jnp.asarray(other_p),
+            rows_p, other_p,
         )
         return np.asarray(out)[:k].astype(np.int64)
 
     def add_pieces(self, rows: np.ndarray, pieces: np.ndarray) -> None:
         """Piece completions: scatter ``have[rows, pieces] = True`` and
         bump replica counts (padded with out-of-bounds drops)."""
-        jnp = self._jnp
         k = rows.size
         kp = _next_pow2(k, 3)
         r = np.full(kp, self.n, dtype=np.int32)
         p = np.full(kp, self.P, dtype=np.int32)
         r[:k] = rows
         p[:k] = pieces
-        self.have, self.repl = _add_pieces_jit()(
-            self.have, self.repl, jnp.asarray(r), jnp.asarray(p)
-        )
+        self.have, self.repl = _add_pieces(self.have, self.repl, r, p)
 
     def drop_rows(self, rows: np.ndarray) -> None:
         """Departures: remove the rows' held pieces from the replica
         counts (the have rows themselves stay, as on the host)."""
-        jnp = self._jnp
         k = rows.size
         kp = _next_pow2(k, 3)
         r = np.full(kp, self.n, dtype=np.int32)  # OOB gather -> fill False
         r[:k] = rows
-        self.repl = _drop_rows_jit()(self.have, self.repl, jnp.asarray(r))
+        self.repl = _drop_rows(self.have, self.repl, r)
+
+    def waterfill(self, src, dst, up_cap, down_cap, link_of, link_cap):
+        """:func:`fleet_waterfill` on the device, keeping the run's
+        water-fill statistics."""
+        rates, rounds, plan = _waterfill(
+            src, dst, up_cap, down_cap, link_of, link_cap, None,
+            self.interpret,
+        )
+        self.waterfill_runs[plan.impl] += 1
+        self.peak_flows = max(self.peak_flows, np.asarray(src).size)
+        self.rounds += rounds
+        return rates

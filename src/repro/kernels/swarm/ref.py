@@ -9,11 +9,11 @@ Three oracles, three exactness contracts:
   hot path), re-exported so the parity suite pins kernel == engine.
 
 - :func:`waterfill_jnp_ref` — the *bit-for-bit* water-filling oracle
-  (checksum-idiom pure-jnp): the same fixed point as the kernel, but
-  unpadded, untiled, scatter-based, compiled through the same XLA
-  pipeline. Comparing the kernel against it pins exactly what the kernel
-  adds — flow tiling, the padding conventions, the dummy link slot, and
-  the one-hot segment math — with zero tolerance.
+  (checksum-idiom pure-jnp): the XLA fixed point
+  :func:`~.kernel.waterfill_xla` on the unpadded table, scatter-based.
+  Comparing both device paths against it pins exactly what they add —
+  flow tiling, the padding conventions, the dummy link slot, and the
+  kernel's one-hot segment math — with zero tolerance.
 
 - :func:`waterfill_f32_ref` — a float32 numpy transliteration of
   :func:`repro.core.fleet.waterfill_rates` (same bincount / min ordering,
@@ -30,10 +30,12 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ... import jax_compat
 from ...core.piece_selection import batched_rarest
+from .kernel import waterfill_xla
 
 F32 = np.float32
 F32_INF = np.float32(np.inf)
@@ -127,54 +129,7 @@ def waterfill_f32_ref(
 
 @functools.lru_cache(maxsize=None)
 def _jnp_fill(n_iter: int):
-    import jax.numpy as jnp
-    from jax import lax
-
-    def fn(src, dst, lnk, up, dn, lcap):
-        nn = up.shape[0]
-        pnl = lcap.shape[0]
-
-        def body(state):
-            rate, frozen, up_a, dn_a, lk_a, it, done = state
-            act = (~frozen).astype(jnp.float32)
-            n_up = jnp.zeros(nn, jnp.float32).at[src].add(act)
-            n_dn = jnp.zeros(nn, jnp.float32).at[dst].add(act)
-            n_lk = jnp.zeros(pnl, jnp.float32).at[lnk].add(act)
-            du = jnp.where(n_up > 0, (up - up_a) / n_up, jnp.inf)
-            dd = jnp.where(n_dn > 0, (dn - dn_a) / n_dn, jnp.inf)
-            dl = jnp.where(n_lk > 0, (lcap - lk_a) / n_lk, jnp.inf)
-            delta = jnp.minimum(jnp.minimum(du.min(), dd.min()), dl.min())
-            ok = jnp.isfinite(delta)
-            delta = jnp.where(ok, jnp.maximum(delta, jnp.float32(0.0)), 0.0)
-            rate = rate + act * delta
-            up_a = up_a + n_up * delta
-            dn_a = dn_a + n_dn * delta
-            lk_a = lk_a + n_lk * delta
-            tol = delta + jnp.float32(1e-6)
-            sat_u = ((du <= tol) & (n_up > 0)).astype(jnp.float32)
-            sat_d = ((dd <= tol) & (n_dn > 0)).astype(jnp.float32)
-            sat_l = ((dl <= tol) & (n_lk > 0)).astype(jnp.float32)
-            newly = (~frozen) & ((sat_u[src] + sat_d[dst] + sat_l[lnk]) > 0)
-            done = ~(ok & newly.any())
-            return (rate, frozen | newly, up_a, dn_a, lk_a, it + 1, done)
-
-        def cond(state):
-            _, frozen, _, _, _, it, done = state
-            return (~done) & (it < n_iter) & (~frozen.all())
-
-        nf = src.shape[0]
-        init = (
-            jnp.zeros(nf, jnp.float32),
-            jnp.zeros(nf, dtype=bool),
-            jnp.zeros(nn, jnp.float32),
-            jnp.zeros(nn, jnp.float32),
-            jnp.zeros(pnl, jnp.float32),
-            jnp.int32(0),
-            jnp.asarray(False),
-        )
-        return lax.while_loop(cond, body, init)[0]
-
-    return jax_compat.jit(fn)
+    return jax.jit(functools.partial(waterfill_xla, n_iter=n_iter))
 
 
 def waterfill_jnp_ref(
@@ -185,14 +140,13 @@ def waterfill_jnp_ref(
     link_of: Optional[np.ndarray] = None,
     link_cap: Optional[np.ndarray] = None,
 ) -> np.ndarray:
-    """Pure-jnp water-filling oracle: unpadded, untiled, scatter-based.
+    """Pure-jnp water-filling oracle: :func:`~.kernel.waterfill_xla` on the
+    unpadded, untiled table.
 
-    The kernel must match this *bit for bit* in both segment modes — the
-    diff between the two is precisely the machinery under test (tiling,
-    padding, dummy slots, one-hot segment sums).
+    Both device paths must match this *bit for bit* — the diff is
+    precisely the machinery under test (the kernel's tiling and one-hot
+    segment sums; both paths' padding and dummy slots).
     """
-    import jax.numpy as jnp
-
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     nf = src.size
@@ -207,5 +161,5 @@ def waterfill_jnp_ref(
         jnp.asarray(np.asarray(up_cap, dtype=F32)),
         jnp.asarray(np.asarray(down_cap, dtype=F32)),
         jnp.asarray(lcap),
-    )
+    )[0]
     return np.asarray(out)

@@ -39,7 +39,6 @@ from ..models.model import default_positions
 from ..train import optimizer as opt
 from ..train.train_step import TrainState, make_train_step
 from . import hlo_analysis as hlo
-from ..jax_compat import cost_analysis, set_mesh
 from .mesh import make_production_mesh, make_test_mesh
 from .partitioning import Partitioner, batch_shardings
 
@@ -227,13 +226,13 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: Path,
     try:
         # (1) the deployed artifact: scan-over-layers + remat. This is what
         # memory_analysis must be read from (the real activation schedule).
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered, tokens = lower_cell(cfg, shape, mesh, arch,
                                          train_overrides=train_overrides)
             compiled = lowered.compile()
             mem = compiled.memory_analysis()
             coll_scanned = hlo.collective_bytes(compiled.as_text())
-            cost_scanned = cost_analysis(compiled)
+            cost_scanned = compiled.cost_analysis()
         t_main = time.time() - t0
 
         # (2) XLA's cost_analysis counts a while-loop (scan) body ONCE, so
@@ -247,11 +246,11 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: Path,
                 num_layers=len(cfg.block_pattern) * depth + len(cfg.tail_pattern),
                 encoder_layers=depth if cfg.encoder_layers else 0,
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 low, _ = lower_cell(pcfg, shape, mesh, arch, scan_layers=False,
                                     train_overrides=train_overrides)
                 comp = low.compile()
-                return cost_analysis(comp), hlo.collective_bytes(comp.as_text())
+                return comp.cost_analysis(), hlo.collective_bytes(comp.as_text())
 
         g = cfg.group_count
         if cfg.encoder_layers:

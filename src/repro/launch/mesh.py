@@ -13,11 +13,14 @@ Production target: TPU v5e pods, 256 chips each.
 from __future__ import annotations
 
 import jax
-
-from ..jax_compat import AxisType, make_mesh as _mesh
+from jax.sharding import AxisType
 
 __all__ = ["AxisType", "make_production_mesh", "make_test_mesh",
            "batch_axes", "dp_size"]
+
+
+def _mesh(shape, axes) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

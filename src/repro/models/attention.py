@@ -21,9 +21,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import get_abstract_mesh
 
 from ..configs.base import ModelConfig
-from ..jax_compat import get_abstract_mesh, shard_map
 from .layers import (
     EMBED, HEADDIM, KVHEADS, QHEADS,
     ParamSpec, apply_rope, constrain_bshd, qk_norm, softcap,
@@ -450,7 +450,7 @@ def decode_step_split_kv(
         ks = jnp.zeros((cache["k"].shape[0], smax, cache["k"].shape[2], 1),
                        jnp.bfloat16)
         vs = ks
-    out, kc, vc, ks, vs = shard_map(
+    out, kc, vc, ks, vs = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(), cache_spec, cache_spec, cache_spec,

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..jax_compat import AxisType, get_abstract_mesh
+from jax.sharding import AxisType, get_abstract_mesh
 
 # logical axis vocabulary (see launch/partitioning.py for the mesh rules)
 LAYERS, EMBED, MLP, VOCAB = "layers", "embed", "mlp", "vocab"

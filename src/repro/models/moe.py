@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
-from ..jax_compat import shard_map
 from .layers import EMBED, EXPERTS, EXPERTS_DP, MLP, ParamSpec, mlp_apply, mlp_specs
 
 
@@ -241,7 +240,7 @@ def moe_apply_a2a(
                  "model" if "model" in mesh.shape else None)
     wspec_down = P("data" if "data" in mesh.shape else None,
                    "model" if "model" in mesh.shape else None, None)
-    y, lb, z = shard_map(
+    y, lb, z = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -315,7 +314,7 @@ def moe_apply(
                 z = jax.lax.psum(z, dp) / denom
             return y_loc, lb, z
 
-        y, lb, z = shard_map(
+        y, lb, z = jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(

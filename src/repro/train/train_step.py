@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import TrainConfig
-from ..jax_compat import shard_map
 from ..models.model import ModelBundle
 from . import optimizer as opt
 
@@ -152,7 +151,7 @@ def make_train_step(
 
     def compressed_step(state: TrainState, batch: dict):
         batch_specs = {k: P(pod_axis) for k in batch}       # batch split by pod
-        return shard_map(
+        return jax.shard_map(
             pod_local_step,
             mesh=mesh,
             in_specs=(P(), batch_specs),                    # params/opt replicated across pods

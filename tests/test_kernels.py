@@ -79,6 +79,18 @@ def test_checksum_vs_ref_and_detects_corruption():
     assert not verify_replicas([got, device_checksum(y, block=512)])
 
 
+@pytest.mark.parametrize("dtype,lo,hi", [
+    (np.uint8, 0, 256),             # byte tiles, widened inside the kernel
+    (np.int32, -2**31, 2**31 - 1),  # high bit set: unsigned words mod 65521
+])
+def test_checksum_full_word_range_vs_ref(dtype, lo, hi):
+    x = RNG.integers(lo, hi, (64, 128), endpoint=True).astype(dtype)
+    got = device_checksum(jnp.asarray(x), block=2048)
+    want = checksum_ref(jnp.asarray(x.reshape(-1)).astype(jnp.uint32),
+                        block=2048)
+    assert bool((got == want).all())
+
+
 def test_checksum_any_dtype():
     f = jnp.asarray(RNG.normal(size=(33, 65)), jnp.float32)
     c1, c2 = device_checksum(f), device_checksum(f + 1e-3)

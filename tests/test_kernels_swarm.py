@@ -3,9 +3,9 @@
 Three exactness tiers (see ``repro/kernels/swarm/ref.py``):
 
 - rarest-argmin is *index-exact* against the numpy engine hot path;
-- the water-filling kernel is *bit-exact* against the pure-jnp oracle in
-  both segment modes (tiling / padding / dummy-slot machinery adds
-  nothing);
+- both device water-fill paths (the Pallas kernel and the padded XLA
+  fixed point) are *bit-exact* against the pure-jnp oracle (tiling /
+  padding / dummy-slot machinery adds nothing);
 - against numpy references it holds a tight relative band (XLA:CPU fuses
   ``alloc + count * delta`` into FMAs; numpy rounds twice), and the
   engine-level test pins that the band never moves a piece completion on
@@ -13,13 +13,14 @@ Three exactness tiers (see ``repro/kernels/swarm/ref.py``):
   exactly.
 """
 
+import functools
 import json
 import pathlib
+import sys
 
 import numpy as np
 import pytest
 
-from repro import jax_compat
 from repro.core.fleet import waterfill_rates
 from repro.core.piece_selection import batched_rarest
 from repro.kernels.swarm import (
@@ -28,10 +29,6 @@ from repro.kernels.swarm import (
     rarest_argmin,
     waterfill_f32_ref,
     waterfill_jnp_ref,
-)
-
-pytestmark = pytest.mark.skipif(
-    not jax_compat.HAS_PALLAS, reason="jax.experimental.pallas unavailable"
 )
 
 RNG = np.random.default_rng(7)
@@ -120,18 +117,18 @@ def _random_topology(nf, nn, spine=False, inf_caps=False):
 
 @pytest.mark.parametrize("nf,nn", [(1, 2), (5, 3), (37, 10), (300, 40)])
 @pytest.mark.parametrize("spine", [False, True])
-@pytest.mark.parametrize("segments", ["scatter", "onehot"])
-def test_waterfill_bit_exact_vs_jnp_oracle(nf, nn, spine, segments):
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_waterfill_bit_exact_vs_jnp_oracle(nf, nn, spine, impl):
     src, dst, up, dn, lof, lcap = _random_topology(nf, nn, spine=spine)
-    out = fleet_waterfill(src, dst, up, dn, lof, lcap, segments=segments)
+    out = fleet_waterfill(src, dst, up, dn, lof, lcap, impl=impl)
     ref = waterfill_jnp_ref(src, dst, up, dn, lof, lcap)
     np.testing.assert_array_equal(out.astype(np.float32), ref)
 
 
 def test_waterfill_bit_exact_with_inf_caps():
     src, dst, up, dn, lof, lcap = _random_topology(80, 12, inf_caps=True)
-    for segments in ("scatter", "onehot"):
-        out = fleet_waterfill(src, dst, up, dn, segments=segments)
+    for impl in ("xla", "pallas"):
+        out = fleet_waterfill(src, dst, up, dn, impl=impl)
         np.testing.assert_array_equal(
             out.astype(np.float32), waterfill_jnp_ref(src, dst, up, dn)
         )
@@ -165,6 +162,33 @@ def test_waterfill_empty_and_zero_cap():
     np.testing.assert_array_equal(out, np.zeros(4))
 
 
+def _count_compiles(fn):
+    import jax
+
+    seen = []
+
+    def on_compile(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    return len(seen)
+
+
+def test_waterfill_compiles_once_per_padded_shape():
+    # flow counts inside one power-of-two bucket reuse one executable: a
+    # fleet run sees a new flow count nearly every tick
+    src, dst, up, dn, _, _ = _random_topology(300, 40)
+    fleet_waterfill(src[:200], dst[:200], up, dn)  # warm the 256 bucket
+    assert _count_compiles(lambda: [
+        fleet_waterfill(src[:nf], dst[:nf], up, dn) for nf in (131, 199, 256)
+    ]) == 0
+
+
 # ------------------------------------------------------------------ device state
 
 
@@ -192,6 +216,18 @@ def test_device_state_tracks_incremental_updates():
     repl -= have[drop].sum(axis=0)
     dev.drop_rows(drop)
     np.testing.assert_array_equal(np.asarray(dev.repl), repl)
+
+
+def test_device_select_compiles_once_per_row_bucket():
+    n, P = 300, 45
+    dev = FleetDeviceState(RNG.random((n, P), dtype=np.float32),
+                           RNG.random(P) < 0.6)
+    sel = functools.partial(dev.select, stream="swarm", mode="swarm_first",
+                            fallback=True)
+    sel(np.arange(140), np.full(140, -1))  # warm the 256-row bucket
+    assert _count_compiles(lambda: [
+        sel(np.arange(k), np.full(k, -1)) for k in (129, 200, 256)
+    ]) == 0
 
 
 @pytest.mark.parametrize("stream,mode,fallback", [
@@ -237,22 +273,23 @@ def test_device_select_matches_engine_cand_build(stream, mode, fallback):
 # ------------------------------------------------------------------ engine parity
 
 
-def test_fleet_backend_pallas_falls_back_without_pallas(monkeypatch):
-    # no Pallas in the installed jax -> warn once and degrade to the jit
-    # water-filling path instead of failing the run
+def test_fleet_backend_pallas_raises_without_pallas(monkeypatch):
+    # no Pallas in the installed jax -> the device backend fails loudly;
+    # nothing turns backend="pallas" into another path
     from repro.core.fleet import FleetSpec, FleetSwarmSim
     from repro.core.metainfo import MetaInfo
     from repro.core.webseed import MirrorSpec
 
-    monkeypatch.setattr("repro.jax_compat.HAS_PALLAS", False)
+    for name in [m for m in sys.modules if m.startswith("repro.kernels")]:
+        monkeypatch.delitem(sys.modules, name)
+    monkeypatch.setitem(sys.modules, "jax.experimental.pallas", None)
     mi = MetaInfo.from_sizes_only(int(64e6), int(8e6), name="x")
     sim = FleetSwarmSim(mi, fleet=FleetSpec(backend="pallas"))
     sim.add_mirrors([MirrorSpec("origin", up_bps=50e6)])
     sim.add_peers([("p0", 0.0)], up_bps=25e6, down_bps=50e6)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        res = sim.run()
-    assert sim._backend == "jit" and sim._dev is None
-    assert res.completed == 1
+    with pytest.raises(ImportError, match="pallas"):
+        sim.run()
+    assert sim.fleet_cfg.backend == "pallas"
 
 
 def test_fleet_backend_pallas_matches_numpy_engine():

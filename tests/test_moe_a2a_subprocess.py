@@ -19,7 +19,6 @@ from repro.configs import get_config
 from repro.launch.mesh import make_test_mesh
 from repro.models.layers import init_params
 from repro.models.moe import EPContext, moe_apply, moe_specs
-from repro.jax_compat import set_mesh
 
 cfg = get_config("dbrx_132b").reduce(num_experts=4, top_k=2, d_model=32,
                                      d_ff=64, vocab_size=128)
@@ -29,7 +28,7 @@ params = init_params(moe_specs(cfg), jax.random.key(0), jnp.float32)
 x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 8, 32)), jnp.float32)
 y_ref, aux_ref = moe_apply(params, x, cfg, EPContext())
 mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y, aux = jax.jit(lambda p, xx: moe_apply(p, xx, cfg_a2a, EPContext(mesh=mesh)))(params, x)
 err = float(jnp.max(jnp.abs(np.asarray(y) - y_ref)))
 assert err < 3e-2, err           # bf16 wire quantization bound
@@ -40,7 +39,7 @@ assert abs(float(aux["lb"]) - float(aux_ref["lb"])) < 0.25
 def loss(p):
     yy, aa = moe_apply(p, x, cfg_a2a, EPContext(mesh=mesh))
     return jnp.sum(yy ** 2) + aa["lb"]
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(params)
 gn = sum(float(jnp.abs(v).sum()) for v in jax.tree.leaves(g))
 assert np.isfinite(gn) and gn > 0

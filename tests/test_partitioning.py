@@ -1,9 +1,8 @@
 """Partitioner: rule table, divisibility fallback, FSDP+TP assignment."""
 
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.jax_compat import abstract_mesh
 from repro.launch.mesh import make_test_mesh
 from repro.launch.partitioning import Partitioner
 
@@ -15,7 +14,7 @@ def part():
 
 def mesh_16():
     # abstract meshes don't need real devices; use AbstractMesh for rules
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_fsdp_plus_tp_2d(part):
@@ -48,7 +47,7 @@ def test_mesh_axis_used_once_per_array():
 
 
 def test_multipod_batch_axes():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     big = Partitioner(mesh)
     assert big.spec((256, 4096), ("batch", None)) == P(("pod", "data"), None)
 
