@@ -10,10 +10,6 @@ wall budget); the headline number is **µs per client-tick** in the row's
 ``--compare`` gate deliberately ignores so only the simulation outcomes
 (completion time, U/D, origin copies) are pinned.
 
-Each fleet row is followed by ``_phase_*`` rows carrying the engine's
-per-phase wall breakdown (select / waterfill / bookkeeping / telemetry)
-in the ignored wall column — constant derived text, so they pin nothing.
-
 The ``fleet_pallas_n2000`` row re-runs the 2k crowd with
 ``backend="pallas"`` (interpret mode on CPU CI). Its float32 water-fill
 rates can quantize a completion a tick differently across jax/XLA
@@ -38,7 +34,6 @@ PIECE = 32e6
 FLEET_NS = (2_000, 10_000, 100_000)
 FLEET_1M = 1_000_000
 FLEET_1M_DT = 16.0  # coarser ticks keep the 1M point inside the CI budget
-PHASES = ("select", "waterfill", "bookkeeping", "telemetry")
 
 
 def flash(n, endgame=True, fail_frac=0.0, seed=0):
@@ -68,7 +63,7 @@ def fleet_point(spec: ScenarioSpec, n: int, backend=None, dt=None):
 
 
 def fleet_row(report, name: str, res, n: int, wall: float, derived=None):
-    """One pinned outcome row + its per-phase wall rows (never pinned)."""
+    """One pinned outcome row."""
     done = np.isfinite(res.completed_at)
     t_all = float(res.completed_at[done].max())
     if derived is None:
@@ -78,9 +73,6 @@ def fleet_row(report, name: str, res, n: int, wall: float, derived=None):
             f"done={int(done.sum())}/{res.n}"
         )
     report(name, wall * 1e6 / (n * res.ticks), derived)
-    for phase in PHASES:
-        report(f"{name}_phase_{phase}",
-               res.phase_seconds[phase] * 1e6, "wall-only")
     return t_all
 
 

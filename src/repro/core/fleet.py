@@ -51,6 +51,31 @@ engine, not a re-implementation:
   N (pinned by ``tests/test_fleet.py``), and the scaling *shape* — the
   paper's self-scaling claim — is preserved.
 
+Profile spans
+-------------
+Under a JAX profile the tick marks its host work as spans on the
+profiler's clock (:class:`~repro.core.spans.Span`), beside the device's
+operations:
+
+* ``fleet.tick`` — one tick that advances the clock, from the decision
+  to advance to its bookkeeping. The due events, departures and masks
+  before that decision lie outside it, as do a ``run`` call's last pass
+  (which stops at ``until``) and idle fast-forwards;
+* ``fleet.select`` — one selection call on one stream
+  (``phase_seconds["select"]``);
+* ``fleet.resample`` — sampling the source tables of a set of rows;
+* ``fleet.flow_table`` — HTTP admission and the flow table, choking
+  included;
+* ``fleet.waterfill`` — one rate allocation
+  (``phase_seconds["waterfill"]``); on the device path its metadata
+  ``rounds`` holds the call's fixed-point rounds;
+* ``fleet.completions`` — the chained completion rounds, with their
+  selections and resampling nested inside;
+* ``fleet.telemetry`` — the metrics sampler
+  (``phase_seconds["telemetry"]``).
+
+``phase_seconds["bookkeeping"]`` is the rest of each tick's wall time.
+
 Tick quantization: arrivals activate at the first tick boundary >= their
 arrival time; fault events snap the tick so they fire on their exact
 timestamp; completions are stamped at the end of the tick that delivered
@@ -77,6 +102,7 @@ from .scheduler import (
     spec_to_dict,
     swarm_routed_mask,
 )
+from .spans import Span
 from .swarm import SwarmConfig
 from .telemetry import NULL_RECORDER
 from .webseed import MirrorSpec
@@ -665,38 +691,38 @@ class FleetSwarmSim:
             return
         if stream == "http" and not live_mirror:
             return
-        t0 = perf_counter()
-        other = (
-            self.cur_swarm[rows] if stream == "http"
-            else self.cur_http[rows]
-        )
-        if self.device is not None:
-            # device path: cand mask built on the accelerator, only the
-            # (k,) pick vector crosses back
-            pick = self.device.select(
-                rows, other, stream=stream,
-                mode=self.policy.mode,
-                fallback=self.policy.http_fallback,
+        with Span("fleet.select", self.phase_seconds, "select"):
+            other = (
+                self.cur_swarm[rows] if stream == "http"
+                else self.cur_http[rows]
             )
-        else:
-            missing = ~self.have[rows]
-            if stream == "http":
-                if self.policy.mode == "http_first":
-                    cand = missing.copy()
-                else:
-                    cand = missing & ~self.swarm_class[None, :]
-                    if self.policy.http_fallback:
-                        # origin rescue for swarm-routed pieces nobody serves
-                        cand |= missing & self.swarm_class[None, :] \
-                            & (self.replicas == 0)[None, :]
+            if self.device is not None:
+                # device path: cand mask built on the accelerator, only the
+                # (k,) pick vector crosses back
+                pick = self.device.select(
+                    rows, other, stream=stream,
+                    mode=self.policy.mode,
+                    fallback=self.policy.http_fallback,
+                )
             else:
-                cand = missing & self.swarm_class[None, :] \
-                    & (self.replicas > 0)[None, :]
-            has_other = other >= 0
-            if has_other.any():
-                cand[np.flatnonzero(has_other), other[has_other]] = False
-            pick = batched_rarest(cand, self.replicas, self.jitter[rows])
-        self.phase_seconds["select"] += perf_counter() - t0
+                missing = ~self.have[rows]
+                if stream == "http":
+                    if self.policy.mode == "http_first":
+                        cand = missing.copy()
+                    else:
+                        cand = missing & ~self.swarm_class[None, :]
+                        if self.policy.http_fallback:
+                            # origin rescue for swarm-routed pieces nobody
+                            # serves
+                            cand |= missing & self.swarm_class[None, :] \
+                                & (self.replicas == 0)[None, :]
+                else:
+                    cand = missing & self.swarm_class[None, :] \
+                        & (self.replicas > 0)[None, :]
+                has_other = other >= 0
+                if has_other.any():
+                    cand[np.flatnonzero(has_other), other[has_other]] = False
+                pick = batched_rarest(cand, self.replicas, self.jitter[rows])
         if stream == "http":
             self.cur_http[rows] = pick
             self.prog_http[rows[pick < 0]] = 0.0
@@ -711,27 +737,77 @@ class FleetSwarmSim:
         small-N graph the equivalence gate relies on)."""
         if rows.size == 0:
             return
-        self.src_tab[rows] = -1
-        present = self._present
-        pieces = self.cur_swarm[rows]
-        for p in np.unique(pieces):
-            grp = rows[pieces == p]
-            holders = np.flatnonzero(self.have[:, p] & present)
-            if holders.size == 0:
-                continue
-            if holders.size <= self.fanout:
-                self.src_tab[grp[:, None], np.arange(holders.size)[None, :]] \
-                    = holders[None, :]
-            else:
-                self.src_tab[grp] = holders[
-                    self.rng.integers(
-                        0, holders.size, (grp.size, self.fanout)
+        with Span("fleet.resample"):
+            self.src_tab[rows] = -1
+            present = self._present
+            pieces = self.cur_swarm[rows]
+            for p in np.unique(pieces):
+                grp = rows[pieces == p]
+                holders = np.flatnonzero(self.have[:, p] & present)
+                if holders.size == 0:
+                    continue
+                if holders.size <= self.fanout:
+                    self.src_tab[
+                        grp[:, None], np.arange(holders.size)[None, :]
+                    ] = holders[None, :]
+                else:
+                    self.src_tab[grp] = holders[
+                        self.rng.integers(
+                            0, holders.size, (grp.size, self.fanout)
+                        )
+                    ]
+            # no self-serving
+            self.src_tab[rows] = np.where(
+                self.src_tab[rows] == rows[:, None], -1, self.src_tab[rows]
+            )
+
+    def _complete(self, mirror_of: np.ndarray, live_mirror: bool) -> None:
+        """Credit every stream whose progress covers its piece and select
+        its next piece, round after round while any stream finishes (a
+        fat pipe can finish several pieces in one tick; chained selection
+        keeps streams busy)."""
+        for _ in range(self.num_pieces + 1):
+            did = False
+            for stream in ("http", "swarm"):
+                cur = self.cur_http if stream == "http" else self.cur_swarm
+                prog = (
+                    self.prog_http if stream == "http"
+                    else self.prog_swarm
+                )
+                rows = np.flatnonzero(
+                    (cur >= 0)
+                    & (prog >= self.piece_sizes[np.clip(cur, 0, None)]
+                       - 1e-6)
+                )
+                if rows.size == 0:
+                    continue
+                did = True
+                pieces = cur[rows]
+                sizes = self.piece_sizes[pieces]
+                # duplicate-free by construction (selection never picks
+                # a held piece and the two streams exclude each other)
+                self.have[rows, pieces] = True
+                self.nhave[rows] += 1
+                np.add.at(self.replicas, pieces, 1)
+                if self.device is not None:
+                    self.device.add_pieces(rows, pieces)
+                prog[rows] -= sizes
+                self.downloaded[rows] += sizes
+                was_http_class = ~self.swarm_class[pieces]
+                np.add.at(
+                    self.n_missing_http, rows[was_http_class], -1
+                )
+                np.add.at(
+                    self.n_missing_swarm, rows[~was_http_class], -1
+                )
+                if stream == "http":
+                    np.add.at(
+                        self.mirror_uploaded, mirror_of[rows], sizes
                     )
-                ]
-        # no self-serving
-        self.src_tab[rows] = np.where(
-            self.src_tab[rows] == rows[:, None], -1, self.src_tab[rows]
-        )
+                cur[rows] = -1
+                self._select(rows, stream, live_mirror)
+            if not did:
+                return
 
     # ------------------------------------------------------------- run
     def run(self, until: float = INF, max_ticks: int = 10_000_000):
@@ -804,204 +880,183 @@ class FleetSwarmSim:
             if dt <= 0:
                 break
 
-            live_rank = [m for m in self._mirror_rank if self.mirror_alive[m]]
-            # --- expire stale fallback picks: a swarm-routed piece queued
-            # for origin rescue while it had no replicas goes back to the
-            # swarm the moment holders appear — only unstarted streams
-            # (zero progress) switch, mid-range fetches keep their bytes.
-            # Without this, peers that queued during bootstrap drain
-            # through the admission cap in O(n) waves at fleet scale.
-            if self.policy.mode == "swarm_first":
-                rows = np.flatnonzero(
-                    leech & (self.cur_http >= 0) & (self.prog_http <= 0.0)
-                )
-                if rows.size:
-                    picks = self.cur_http[rows]
-                    stale = self.swarm_class[picks] & (self.replicas[picks] > 0)
-                    self.cur_http[rows[stale]] = -1
-            # --- piece selection (only rows with an idle stream)
-            self._select(
-                np.flatnonzero(leech & (self.cur_http < 0)),
-                "http", bool(live_rank),
-            )
-            if self.replicas.max() > 0:
-                self._select(
-                    np.flatnonzero(leech & (self.cur_swarm < 0)),
-                    "swarm", bool(live_rank),
-                )
-            # --- rechoke: resample every source table periodically
-            if self.ticks % self.rechoke_ticks == 0:
-                self._resample_sources(
-                    np.flatnonzero(leech & (self.cur_swarm >= 0))
-                )
-
-            # --- HTTP admission: index order (FCFS for a flash crowd),
-            # ranked live mirrors fill to their admission caps in turn
-            http_rows = np.flatnonzero(leech & (self.cur_http >= 0))
-            mirror_of = np.full(self.n, -1, dtype=np.int64)
-            if live_rank:
-                lo = 0
-                for m in live_rank:
-                    hi = min(lo + int(caps[m]), http_rows.size)
-                    mirror_of[http_rows[lo:hi]] = m
-                    lo = hi
-                    if lo >= http_rows.size:
-                        break
-            admitted = http_rows[mirror_of[http_rows] >= 0]
-
-            # --- flow table: peers 0..n-1, mirrors n..n+M-1
-            n = self.n
-            swarm_rows = np.flatnonzero(leech & (self.cur_swarm >= 0))
-            s_src = self.src_tab[swarm_rows].ravel()
-            s_dst = np.repeat(swarm_rows, self.fanout)
-            keep = (s_src >= 0) & present[np.clip(s_src, 0, None)]
-            s_src, s_dst = s_src[keep], s_dst[keep]
-            # per-uploader concurrency: drop random excess flows above the
-            # unchoke budget (choking, in aggregate)
-            budget = self.upload_slots // ppr  # distinct-pair slots
-            if s_src.size:
-                cnt = np.bincount(s_src, minlength=n)
-                if (cnt > budget).any():
-                    order = np.lexsort(
-                        (self.rng.random(s_src.size), s_src)
-                    )
-                    ss = s_src[order]
-                    starts = np.zeros(n, dtype=np.int64)
-                    starts[1:] = np.cumsum(np.bincount(ss, minlength=n))[:-1]
-                    rank = np.arange(ss.size) - starts[ss]
-                    keep2 = np.zeros(s_src.size, dtype=bool)
-                    keep2[order] = rank < budget
-                    s_src, s_dst = s_src[keep2], s_dst[keep2]
-            # per-peer-requests: each surviving pair carries ppr flows
-            if ppr > 1 and s_src.size:
-                s_src = np.repeat(s_src, ppr)
-                s_dst = np.repeat(s_dst, ppr)
-            h_src = n + mirror_of[admitted]
-            h_dst = admitted
-            fsrc = np.concatenate([s_src, h_src])
-            fdst = np.concatenate([s_dst, h_dst])
-            nsw = s_src.size
-
-            if fsrc.size:
-                link_of = link_cap = None
-                if use_spine:
-                    pod_src = np.where(
-                        fsrc < n, self.pods[np.clip(fsrc, 0, n - 1)], -1
-                    )
-                    pod_dst = self.pods[fdst]
-                    cross = (pod_src != pod_dst) | (pod_src < 0)
-                    link_of = np.where(cross, 0, -1).astype(np.int64)
-                    link_cap = np.array([self.spine_bps])
-                wf_t0 = perf_counter()
-                if self.device is not None:
-                    # both device paths handle spine links natively
-                    rates = self.device.waterfill(
-                        fsrc, fdst, up_cap, down_cap, link_of, link_cap
-                    )
-                elif self.fleet_cfg.backend == "jit" and link_of is None:
-                    rates = _jax_waterfill(fsrc, fdst, up_cap, down_cap)
-                else:
-                    rates = waterfill_rates(
-                        fsrc, fdst, up_cap, down_cap, link_of, link_cap
-                    )
-                ph["waterfill"] += perf_counter() - wf_t0
-                # --- advance one tick
-                sw_in = np.bincount(
-                    fdst[:nsw], weights=rates[:nsw], minlength=n
-                )
-                ht_in = np.bincount(
-                    fdst[nsw:], weights=rates[nsw:], minlength=n
-                )
-                self.prog_swarm += sw_in * dt
-                self.prog_http += ht_in * dt
-                out = np.bincount(
-                    fsrc, weights=rates, minlength=n + M
-                )
-                self.uploaded_wire += out[:n] * dt
-                if use_spine:
-                    self.spine_bytes += float(
-                        rates[link_of >= 0].sum()
-                    ) * dt
-            t_end = t + dt
-            # --- completions (loop: a fat pipe can finish several pieces
-            # in one tick; chained selection keeps streams busy)
-            for _ in range(self.num_pieces + 1):
-                did = False
-                for stream in ("http", "swarm"):
-                    cur = self.cur_http if stream == "http" else self.cur_swarm
-                    prog = (
-                        self.prog_http if stream == "http"
-                        else self.prog_swarm
-                    )
+            with Span("fleet.tick"):
+                live_rank = [
+                    m for m in self._mirror_rank if self.mirror_alive[m]
+                ]
+                # --- expire stale fallback picks: a swarm-routed piece
+                # queued for origin rescue while it had no replicas goes
+                # back to the swarm the moment holders appear — only
+                # unstarted streams (zero progress) switch, mid-range
+                # fetches keep their bytes. Without this, peers that
+                # queued during bootstrap drain through the admission cap
+                # in O(n) waves at fleet scale.
+                if self.policy.mode == "swarm_first":
                     rows = np.flatnonzero(
-                        (cur >= 0)
-                        & (prog >= self.piece_sizes[np.clip(cur, 0, None)]
-                           - 1e-6)
+                        leech & (self.cur_http >= 0)
+                        & (self.prog_http <= 0.0)
                     )
-                    if rows.size == 0:
-                        continue
-                    did = True
-                    pieces = cur[rows]
-                    sizes = self.piece_sizes[pieces]
-                    # duplicate-free by construction (selection never picks
-                    # a held piece and the two streams exclude each other)
-                    self.have[rows, pieces] = True
-                    self.nhave[rows] += 1
-                    np.add.at(self.replicas, pieces, 1)
-                    if self.device is not None:
-                        self.device.add_pieces(rows, pieces)
-                    prog[rows] -= sizes
-                    self.downloaded[rows] += sizes
-                    was_http_class = ~self.swarm_class[pieces]
-                    np.add.at(
-                        self.n_missing_http, rows[was_http_class], -1
-                    )
-                    np.add.at(
-                        self.n_missing_swarm, rows[~was_http_class], -1
-                    )
-                    if stream == "http":
-                        np.add.at(
-                            self.mirror_uploaded, mirror_of[rows], sizes
-                        )
-                    cur[rows] = -1
-                    self._select(rows, stream, bool(live_rank))
-                if not did:
-                    break
-            # stale pools: a stream with no piece must not bank progress
-            self.prog_http[self.cur_http < 0] = 0.0
-            self.prog_swarm[self.cur_swarm < 0] = 0.0
-            # --- peer completion at the end of the delivering tick
-            done_rows = np.flatnonzero(
-                leech & (self.nhave >= self.num_pieces)
-            )
-            if done_rows.size:
-                self.completed_at[done_rows] = t_end
-                finite_linger = np.isfinite(self.linger[done_rows])
-                lrows = done_rows[finite_linger]
-                self.departed_at[lrows] = np.minimum(
-                    self.departed_at[lrows],
-                    t_end + self.linger[lrows],
+                    if rows.size:
+                        picks = self.cur_http[rows]
+                        stale = self.swarm_class[picks] \
+                            & (self.replicas[picks] > 0)
+                        self.cur_http[rows[stale]] = -1
+                # --- piece selection (only rows with an idle stream)
+                self._select(
+                    np.flatnonzero(leech & (self.cur_http < 0)),
+                    "http", bool(live_rank),
                 )
-                if self.telemetry.enabled \
-                        and self.n <= self.peer_event_limit:
-                    for i in done_rows:
-                        self.telemetry.emit(
-                            "peer_complete", t=t_end,
-                            client=self.peer_ids[i], torrent=self.torrent,
-                            nbytes=float(self.downloaded[i]),
+                if self.replicas.max() > 0:
+                    self._select(
+                        np.flatnonzero(leech & (self.cur_swarm < 0)),
+                        "swarm", bool(live_rank),
+                    )
+                # --- rechoke: resample every source table periodically
+                if self.ticks % self.rechoke_ticks == 0:
+                    self._resample_sources(
+                        np.flatnonzero(leech & (self.cur_swarm >= 0))
+                    )
+
+                n = self.n
+                with Span("fleet.flow_table"):
+                    # --- HTTP admission: index order (FCFS for a flash
+                    # crowd), ranked live mirrors fill to their admission
+                    # caps in turn
+                    http_rows = np.flatnonzero(leech & (self.cur_http >= 0))
+                    mirror_of = np.full(n, -1, dtype=np.int64)
+                    if live_rank:
+                        lo = 0
+                        for m in live_rank:
+                            hi = min(lo + int(caps[m]), http_rows.size)
+                            mirror_of[http_rows[lo:hi]] = m
+                            lo = hi
+                            if lo >= http_rows.size:
+                                break
+                    admitted = http_rows[mirror_of[http_rows] >= 0]
+
+                    # --- flow table: peers 0..n-1, mirrors n..n+M-1
+                    swarm_rows = np.flatnonzero(leech & (self.cur_swarm >= 0))
+                    s_src = self.src_tab[swarm_rows].ravel()
+                    s_dst = np.repeat(swarm_rows, self.fanout)
+                    keep = (s_src >= 0) & present[np.clip(s_src, 0, None)]
+                    s_src, s_dst = s_src[keep], s_dst[keep]
+                    # per-uploader concurrency: drop random excess flows
+                    # above the unchoke budget (choking, in aggregate)
+                    budget = self.upload_slots // ppr  # distinct-pair slots
+                    if s_src.size:
+                        cnt = np.bincount(s_src, minlength=n)
+                        if (cnt > budget).any():
+                            order = np.lexsort(
+                                (self.rng.random(s_src.size), s_src)
+                            )
+                            ss = s_src[order]
+                            starts = np.zeros(n, dtype=np.int64)
+                            starts[1:] = np.cumsum(
+                                np.bincount(ss, minlength=n)
+                            )[:-1]
+                            rank = np.arange(ss.size) - starts[ss]
+                            keep2 = np.zeros(s_src.size, dtype=bool)
+                            keep2[order] = rank < budget
+                            s_src, s_dst = s_src[keep2], s_dst[keep2]
+                    # per-peer-requests: each surviving pair carries ppr
+                    # flows
+                    if ppr > 1 and s_src.size:
+                        s_src = np.repeat(s_src, ppr)
+                        s_dst = np.repeat(s_dst, ppr)
+                    h_src = n + mirror_of[admitted]
+                    h_dst = admitted
+                    fsrc = np.concatenate([s_src, h_src])
+                    fdst = np.concatenate([s_dst, h_dst])
+                    nsw = s_src.size
+
+                if fsrc.size:
+                    link_of = link_cap = None
+                    if use_spine:
+                        pod_src = np.where(
+                            fsrc < n, self.pods[np.clip(fsrc, 0, n - 1)], -1
                         )
-            self.now = t_end
-            self.ticks += 1
-            if self.sampler is not None:
-                tel_t0 = perf_counter()
-                while self._next_sample <= self.now + 1e-9:
-                    self.sampler.sample(self._next_sample)
-                    self._next_sample += self.sampler.interval
-                ph["telemetry"] += perf_counter() - tel_t0
-            # bookkeeping = tick wall minus what the timed phases took
-            ph["bookkeeping"] += (perf_counter() - tick_t0) - (
-                ph["select"] + ph["waterfill"] + ph["telemetry"] - snap
-            )
+                        pod_dst = self.pods[fdst]
+                        cross = (pod_src != pod_dst) | (pod_src < 0)
+                        link_of = np.where(cross, 0, -1).astype(np.int64)
+                        link_cap = np.array([self.spine_bps])
+                    with Span("fleet.waterfill", ph, "waterfill") as span:
+                        if self.device is not None:
+                            # both device paths handle spine links natively
+                            rounds = self.device.rounds
+                            rates = self.device.waterfill(
+                                fsrc, fdst, up_cap, down_cap, link_of,
+                                link_cap,
+                            )
+                            span.set_metadata(
+                                rounds=self.device.rounds - rounds
+                            )
+                        elif self.fleet_cfg.backend == "jit" \
+                                and link_of is None:
+                            rates = _jax_waterfill(
+                                fsrc, fdst, up_cap, down_cap
+                            )
+                        else:
+                            rates = waterfill_rates(
+                                fsrc, fdst, up_cap, down_cap, link_of,
+                                link_cap,
+                            )
+                    # --- advance one tick
+                    sw_in = np.bincount(
+                        fdst[:nsw], weights=rates[:nsw], minlength=n
+                    )
+                    ht_in = np.bincount(
+                        fdst[nsw:], weights=rates[nsw:], minlength=n
+                    )
+                    self.prog_swarm += sw_in * dt
+                    self.prog_http += ht_in * dt
+                    out = np.bincount(
+                        fsrc, weights=rates, minlength=n + M
+                    )
+                    self.uploaded_wire += out[:n] * dt
+                    if use_spine:
+                        self.spine_bytes += float(
+                            rates[link_of >= 0].sum()
+                        ) * dt
+                t_end = t + dt
+                # --- completions (loop: a fat pipe can finish several
+                # pieces in one tick; chained selection keeps streams busy)
+                with Span("fleet.completions"):
+                    self._complete(mirror_of, bool(live_rank))
+                # stale pools: a stream with no piece must not bank progress
+                self.prog_http[self.cur_http < 0] = 0.0
+                self.prog_swarm[self.cur_swarm < 0] = 0.0
+                # --- peer completion at the end of the delivering tick
+                done_rows = np.flatnonzero(
+                    leech & (self.nhave >= self.num_pieces)
+                )
+                if done_rows.size:
+                    self.completed_at[done_rows] = t_end
+                    finite_linger = np.isfinite(self.linger[done_rows])
+                    lrows = done_rows[finite_linger]
+                    self.departed_at[lrows] = np.minimum(
+                        self.departed_at[lrows],
+                        t_end + self.linger[lrows],
+                    )
+                    if self.telemetry.enabled \
+                            and self.n <= self.peer_event_limit:
+                        for i in done_rows:
+                            self.telemetry.emit(
+                                "peer_complete", t=t_end,
+                                client=self.peer_ids[i],
+                                torrent=self.torrent,
+                                nbytes=float(self.downloaded[i]),
+                            )
+                self.now = t_end
+                self.ticks += 1
+                if self.sampler is not None:
+                    with Span("fleet.telemetry", ph, "telemetry"):
+                        while self._next_sample <= self.now + 1e-9:
+                            self.sampler.sample(self._next_sample)
+                            self._next_sample += self.sampler.interval
+                # bookkeeping = tick wall minus what the timed phases took
+                ph["bookkeeping"] += (perf_counter() - tick_t0) - (
+                    ph["select"] + ph["waterfill"] + ph["telemetry"] - snap
+                )
         else:
             raise RuntimeError("max_ticks exceeded — runaway fleet run")
         return self._result()

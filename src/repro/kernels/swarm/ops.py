@@ -131,14 +131,19 @@ def waterfill_plan(nf: int, nn: int, nl: int) -> WaterfillPlan:
 
 @functools.lru_cache(maxsize=None)
 def _waterfill_jit(n_iter: int, impl: str, interpret: bool):
-    if impl == "pallas":
-        fn = functools.partial(
-            waterfill_call, n_iter=n_iter, block=BLOCK_FLOWS,
+    # named functions, not partials: a profile shows each program by name
+    def fleet_waterfill_pallas(src, dst, lnk, up, dn, lcap):
+        return waterfill_call(
+            src, dst, lnk, up, dn, lcap, n_iter=n_iter, block=BLOCK_FLOWS,
             vmem_limit_bytes=WATERFILL_VMEM_LIMIT, interpret=interpret,
         )
-    else:
-        fn = functools.partial(waterfill_xla, n_iter=n_iter)
-    return jax.jit(fn)
+
+    def fleet_waterfill_xla(src, dst, lnk, up, dn, lcap):
+        return waterfill_xla(src, dst, lnk, up, dn, lcap, n_iter=n_iter)
+
+    return jax.jit(
+        fleet_waterfill_pallas if impl == "pallas" else fleet_waterfill_xla
+    )
 
 
 def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
@@ -212,7 +217,7 @@ def _select_jit(
     stream_http: bool, http_first: bool, fallback: bool, bp: int,
     interpret: bool,
 ):
-    def fn(have, jitter, repl, swarm_class, rows, other):
+    def fleet_select(have, jitter, repl, swarm_class, rows, other):
         _, P = have.shape
         miss = ~have[rows]  # (k, P) — built and consumed on device
         if stream_http:
@@ -238,11 +243,11 @@ def _select_jit(
             block_rows=BLOCK_ROWS, block_pieces=bp, interpret=interpret,
         )
 
-    return jax.jit(fn)
+    return jax.jit(fleet_select)
 
 
 @jax.jit
-def _add_pieces(have, repl, rows, pieces):
+def fleet_add_pieces(have, repl, rows, pieces):
     # out-of-bounds padding indices are dropped, so one trace serves
     # every power-of-two batch size
     have = have.at[rows, pieces].set(True, mode="drop")
@@ -251,7 +256,7 @@ def _add_pieces(have, repl, rows, pieces):
 
 
 @jax.jit
-def _drop_rows(have, repl, rows):
+def fleet_drop_rows(have, repl, rows):
     got = have.at[rows].get(mode="fill", fill_value=False)
     return repl - got.sum(axis=0).astype(repl.dtype)
 
@@ -319,7 +324,7 @@ class FleetDeviceState:
         p = np.full(kp, self.P, dtype=np.int32)
         r[:k] = rows
         p[:k] = pieces
-        self.have, self.repl = _add_pieces(self.have, self.repl, r, p)
+        self.have, self.repl = fleet_add_pieces(self.have, self.repl, r, p)
 
     def drop_rows(self, rows: np.ndarray) -> None:
         """Departures: remove the rows' held pieces from the replica
@@ -328,7 +333,7 @@ class FleetDeviceState:
         kp = _next_pow2(k, 3)
         r = np.full(kp, self.n, dtype=np.int32)  # OOB gather -> fill False
         r[:k] = rows
-        self.repl = _drop_rows(self.have, self.repl, r)
+        self.repl = fleet_drop_rows(self.have, self.repl, r)
 
     def waterfill(self, src, dst, up_cap, down_cap, link_of, link_cap):
         """:func:`fleet_waterfill` on the device, keeping the run's
