@@ -13,6 +13,15 @@ cross-tile merge is strictly-less lexicographic, so an earlier tile wins
 exact ties and the result is the global first-occurrence argmin, making
 parity with the numpy engine *index-exact*, not a tolerance band.
 
+The fleet engine's device select (:func:`select_rows_call`) runs the same
+tie-break without a ``(k, P)`` candidate matrix: one call copies each
+selected row of the device-resident have and jitter matrices from HBM,
+whole, into VMEM (the next rows' copies overlap these rows' reduction),
+builds the row's candidate mask there from the stream's class rule, the
+replica counts and the other stream's current piece, and reduces it with
+the shared per-tile minimum (:func:`_lex_min`) and strictly-less merge
+(:func:`_lex_merge`).
+
 **Water-filling** — max-min progressive filling as a fixed-point
 ``lax.while_loop`` (all unfrozen flows grow equally until a node or
 spine-link constraint saturates; flows through it freeze; repeat — at
@@ -66,6 +75,44 @@ F32_INF = jnp.inf
 
 # --------------------------------------------------------------------------- rarest-argmin
 
+# index sentinel above any piece index (not a valid pick)
+IDX_SENTINEL = 2**30
+LANES = 128
+
+
+def _lex_min(c, avail, jit, idx, axis):
+    """Lexicographic minimum of ``(avail, jitter, index)`` over the
+    candidates ``c`` along ``axis``, kept as size-1 dims.
+
+    Availability and jitter are compared in stages, never added; among
+    exact ``(avail, jitter)`` ties the lowest index wins. Where no
+    candidate exists the minimum is ``(inf, inf, *)``.
+    """
+    a = jnp.where(c, avail, F32_INF)
+    tile_a = a.min(axis=axis, keepdims=True)
+    # the `c &` guard keeps inf==inf slots of masked entries out
+    jm = jnp.where(c & (a == tile_a), jit, F32_INF)
+    tile_j = jm.min(axis=axis, keepdims=True)
+    tile_i = jnp.where(jm == tile_j, idx, IDX_SENTINEL).min(
+        axis=axis, keepdims=True
+    )
+    return tile_a, tile_j, tile_i
+
+
+def _lex_merge(prev, new):
+    """Elementwise strictly-less merge of ``(avail, jitter, index)``
+    triples: ``new`` replaces ``prev`` only when its ``(avail, jitter)`` is
+    smaller, so on exact ties ``prev`` wins. Merging in ascending piece
+    order therefore keeps the first occurrence."""
+    pa, pj, pi = prev
+    na, nj, ni = new
+    better = (na < pa) | ((na == pa) & (nj < pj))
+    return (
+        jnp.where(better, na, pa),
+        jnp.where(better, nj, pj),
+        jnp.where(better, ni, pi),
+    )
+
 
 def _rarest_argmin_kernel(
     cand_ref, avail_ref, jit_ref, pick_ref, a_min, j_min, i_min,
@@ -80,26 +127,12 @@ def _rarest_argmin_kernel(
         i_min[...] = jnp.full_like(i_min, -1)
 
     c = cand_ref[...]
-    # stage 1: masked availability minimum per row within this piece tile;
-    # every per-row value below is a (rows, 1) column
-    a = jnp.where(c, avail_ref[...], F32_INF)
-    tile_a = a.min(axis=1, keepdims=True)
-    # stage 2: jitter among this tile's minimal-availability candidates
-    # (the `c &` guard keeps inf==inf rows of all-masked tiles out)
-    jm = jnp.where(c & (a == tile_a), jit_ref[...], F32_INF)
-    tile_j = jm.min(axis=1, keepdims=True)
-    # first occurrence of the minimum -> lowest piece index in the tile
-    col = lax.broadcasted_iota(jnp.int32, jm.shape, 1)
-    tile_i = jnp.where(jm == tile_j, col, bp).min(axis=1, keepdims=True)
-    tile_i = tile_i + j * bp
-    prev_a = a_min[...]
-    prev_j = j_min[...]
-    # strictly-less merge: on exact (avail, jitter) ties the earlier tile
-    # (lower piece index) wins, matching the global first-occurrence argmin
-    better = (tile_a < prev_a) | ((tile_a == prev_a) & (tile_j < prev_j))
-    a_min[...] = jnp.where(better, tile_a, prev_a)
-    j_min[...] = jnp.where(better, tile_j, prev_j)
-    i_min[...] = jnp.where(better, tile_i, i_min[...])
+    pid = lax.broadcasted_iota(jnp.int32, c.shape, 1) + j * bp
+    # per row of this piece tile, then merged into the running minimum:
+    # tiles arrive in piece order, so an earlier tile wins exact ties
+    tile = _lex_min(c, avail_ref[...], jit_ref[...], pid, axis=1)
+    best = _lex_merge((a_min[...], j_min[...], i_min[...]), tile)
+    a_min[...], j_min[...], i_min[...] = best
 
     @pl.when(j == npb - 1)
     def _emit():
@@ -147,6 +180,210 @@ def rarest_argmin_call(
         ],
         interpret=interpret,
     )(cand, avail.reshape(1, P), jitter)
+    return picks[:, 0]
+
+
+# --------------------------------------------------------------------------- fused row select
+
+# which pieces a stream may take, before the row's own have bits and the
+# other stream's current piece: FleetSwarmSim._select's class rules
+SELECT_RULES = ("any", "origin", "origin_or_unserved", "swarm_served")
+
+
+def _piece_rule(rule, swarm_class, repl):
+    if rule == "any":  # HTTP stream, http_first
+        return jnp.ones(repl.shape, jnp.bool_)
+    if rule == "origin":  # HTTP stream, swarm_first
+        return ~swarm_class
+    if rule == "origin_or_unserved":  # ... with the origin rescue fallback
+        return ~swarm_class | (repl == 0)
+    return swarm_class & (repl > 0)  # swarm stream: served swarm pieces
+
+
+def _select_rows_kernel(
+    rows_ref, next_ref, other_ref, repl_ref, cls_ref, have_hbm, jit_hbm,
+    pick_ref, have_buf, jit_buf, sem, avail, acc_a, acc_j, acc_i,
+    *, n_pieces: int, rule: str, chunk: int,
+):
+    g = pl.program_id(0)
+    slot = g % 2
+    _, R, width, _ = jit_buf.shape
+    nfull, tail = divmod(width, chunk)
+
+    def copies(s, r, row):
+        return (
+            pltpu.make_async_copy(have_hbm.at[row], have_buf.at[s, r],
+                                  sem.at[0, s]),
+            pltpu.make_async_copy(jit_hbm.at[row], jit_buf.at[s, r],
+                                  sem.at[1, s]),
+        )
+
+    def fetch(idx_ref, s):
+        """Start the whole-row copies of one group; padding rows (-1) copy
+        nothing."""
+        def start(r, carry):
+            row = idx_ref[0, r]
+
+            @pl.when(row >= 0)
+            def _():
+                for cp in copies(s, r, row):
+                    cp.start()
+
+            return carry
+
+        lax.fori_loop(0, R, start, 0)
+
+    @pl.when(g == 0)
+    def _first():
+        # availability where the stream's rule admits the piece, inf
+        # elsewhere (and on the padding past the last piece)
+        repl = repl_ref[...]
+        pid = (lax.broadcasted_iota(jnp.int32, repl.shape, 0) * LANES
+               + lax.broadcasted_iota(jnp.int32, repl.shape, 1))
+        ok = _piece_rule(rule, cls_ref[...] != 0, repl) & (pid < n_pieces)
+        avail[...] = jnp.where(ok, repl.astype(jnp.float32), F32_INF)
+        fetch(rows_ref, 0)
+
+    # double buffering: the next group's rows stream in while this one
+    # is reduced
+    @pl.when(g + 1 < pl.num_programs(0))
+    def _prefetch():
+        fetch(next_ref, 1 - slot)
+
+    local = (lax.broadcasted_iota(jnp.int32, (chunk, LANES), 0) * LANES
+             + lax.broadcasted_iota(jnp.int32, (chunk, LANES), 1))
+
+    def chunk_step(r, other, size):
+        def step(c, acc):
+            off = c * chunk
+            miss = have_buf[slot, r, pl.ds(off, size), :].astype(
+                jnp.int32) == 0
+            # a peer's two streams exclude each other's current piece
+            keep = local[:size] != other - off * LANES
+            a = jnp.where(miss & keep, avail[pl.ds(off, size), :], F32_INF)
+            j = jit_buf[slot, r, pl.ds(off, size), :]
+            # each slot sees its pieces in ascending order, so the
+            # strictly-less merge keeps the first occurrence; the jitter
+            # of a non-candidate (a = inf) only lands where a stays inf
+            return _lex_merge(acc, (a, j, jnp.full(a.shape, c, jnp.int32)))
+
+        return step
+
+    def reduce_row(r, carry):
+        @pl.when(rows_ref[0, r] >= 0)
+        def _():
+            for cp in copies(slot, r, 0):
+                cp.wait()
+            other = other_ref[0, r]
+            init = (jnp.full((chunk, LANES), F32_INF, jnp.float32),
+                    jnp.full((chunk, LANES), F32_INF, jnp.float32),
+                    jnp.zeros((chunk, LANES), jnp.int32))
+            a, j, c = lax.fori_loop(0, nfull, chunk_step(r, other, chunk),
+                                    init)
+            acc_a[r], acc_j[r] = a, j
+            acc_i[r] = c * (chunk * LANES) + local
+            if tail:  # the last, shorter chunk folds into the first slots
+                a, j, c = chunk_step(r, other, tail)(
+                    nfull, (a[:tail], j[:tail], c[:tail]))
+                acc_a[r, :tail], acc_j[r, :tail] = a, j
+                acc_i[r, :tail] = c * (chunk * LANES) + local[:tail]
+
+        @pl.when(rows_ref[0, r] < 0)
+        def _():
+            acc_a[r] = jnp.full((chunk, LANES), F32_INF, jnp.float32)
+
+        return carry
+
+    @pl.when(rows_ref[0, 0] >= 0)
+    def _reduce():
+        lax.fori_loop(0, R, reduce_row, 0)
+        a, j, i = acc_a[...], acc_j[...], acc_i[...]
+        # all R rows at once: over sublanes, then over lanes
+        a, j, i = _lex_min(a < F32_INF, a, j, i, axis=1)
+        a, j, i = _lex_min(a < F32_INF, a, j, i, axis=2)
+        pick_ref[...] = jnp.where(a < F32_INF, i, -1).reshape(R, 1)
+
+    @pl.when(rows_ref[0, 0] < 0)
+    def _padding():
+        pick_ref[...] = jnp.full((R, 1), -1, jnp.int32)
+
+
+def select_rows_vmem_bytes(width: int, rows: int, chunk: int) -> int:
+    """VMEM of one :func:`select_rows_call`: two slots of ``rows`` whole
+    have (uint8) and jitter (float32) rows, the per-row accumulators, and
+    the ``(width, 128)`` piece vectors (availability, and the two inputs
+    double-buffered)."""
+    slab = width * LANES
+    return 2 * rows * slab * 5 + 3 * rows * chunk * LANES * 4 + 5 * slab * 4
+
+
+def select_rows_call(
+    have: jax.Array,
+    jitter: jax.Array,
+    repl: jax.Array,
+    swarm_class: jax.Array,
+    rows: jax.Array,
+    other: jax.Array,
+    *,
+    n_pieces: int,
+    rule: str,
+    rows_per_step: int,
+    chunk: int,
+    vmem_limit_bytes: int,
+    interpret: bool,
+):
+    """Candidate build + rarest-argmin for the selected rows, reading each
+    row of the device state in place.
+
+    ``have`` (uint8 0/1) and ``jitter`` (float32) are ``(n, width, 128)``:
+    row ``i`` holds pieces ``[0, width * 128)`` as one contiguous slab, zero
+    past ``n_pieces``. They stay in HBM; each grid step copies the whole
+    slabs of ``rows_per_step`` selected rows into VMEM (the next step's
+    copies start before this step's reduction) and reduces them in
+    ``(chunk, 128)`` pieces. ``repl`` and ``swarm_class`` (int32, 0/1) are
+    ``(width, 128)`` and stay in VMEM. ``rows`` and ``other`` are
+    ``(kp,)`` int32 with ``kp`` a multiple of ``rows_per_step``; rows
+    ``-1`` (padding, only at the end) are never copied and pick ``-1``.
+    Returns ``(kp,)`` int32 picks, ``-1`` where a row has no candidate.
+    """
+    assert rule in SELECT_RULES, rule
+    kp = rows.shape[0]
+    _, width, _ = jitter.shape
+    R = rows_per_step
+    assert kp % R == 0 and width % 8 == 0 and chunk % 8 == 0
+    chunk = min(chunk, width)
+    ng = kp // R
+    group = pl.BlockSpec((None, 1, R), lambda g: (g, 0, 0),
+                         memory_space=pltpu.SMEM)
+    nxt = pl.BlockSpec((None, 1, R),
+                       lambda g: (jnp.minimum(g + 1, ng - 1), 0, 0),
+                       memory_space=pltpu.SMEM)
+    piece_vec = pl.BlockSpec((width, LANES), lambda g: (0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    by_group = rows.reshape(ng, 1, R)
+    picks = pl.pallas_call(
+        functools.partial(_select_rows_kernel, n_pieces=n_pieces,
+                          rule=rule, chunk=chunk),
+        grid=(ng,),
+        in_specs=[group, nxt, group, piece_vec, piece_vec, hbm, hbm],
+        out_specs=pl.BlockSpec((R, 1), lambda g: (g, 0)),
+        out_shape=jax.ShapeDtypeStruct((kp, 1), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((2, R, width, LANES), have.dtype),
+            pltpu.VMEM((2, R, width, LANES), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((width, LANES), jnp.float32),
+            pltpu.VMEM((R, chunk, LANES), jnp.float32),
+            pltpu.VMEM((R, chunk, LANES), jnp.float32),
+            pltpu.VMEM((R, chunk, LANES), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes,
+        ),
+        interpret=interpret,
+    )(by_group, by_group, other.reshape(ng, 1, R), repl, swarm_class,
+      have, jitter)
     return picks[:, 0]
 
 

@@ -9,15 +9,17 @@ Three layers, mirroring the checksum/attention packages:
   slot) and cache one ``jax.jit`` entry point per static configuration.
 
 - :class:`FleetDeviceState` — what ``FleetSpec.backend = "pallas"`` hangs
-  onto: the ``(n, P)`` have-matrix, the fixed float32 jitter, and the
-  replica counts live on device across ticks. Per-tick selection builds
-  the candidate mask *on device* (the dominant ``(k, P)`` traffic never
-  leaves the accelerator) and transfers back only the ``(k,)`` pick
-  vector; completions/departures are incremental scatter updates sized by
-  the number of finished pieces, not by ``n * P``. Padding rows use
-  out-of-bounds indices, which jax scatter semantics drop (``mode="drop"``
-  made explicit below), so variable-size updates reuse a handful of
-  power-of-two traces.
+  onto: the have matrix, the fixed float32 jitter, and the replica counts
+  live on device across ticks. Have and jitter are kept row-contiguous,
+  ``(n, width, 128)`` with ``width * 128 >= P``, so that selection
+  (:func:`~.kernel.select_rows_call`, one Pallas call) copies just the
+  selected rows from HBM, whole, builds their candidate masks in VMEM and
+  returns only the ``(k,)`` pick vector: no ``(k, P)`` array and no copy
+  of the state is made per call. Completions/departures are incremental
+  scatter updates sized by the number of finished pieces, not by
+  ``n * P``. Padding rows use out-of-bounds indices, which jax scatter
+  semantics drop (``mode="drop"`` made explicit below), so variable-size
+  updates reuse a handful of power-of-two traces.
 
 ``interpret=None`` resolves per platform
 (:func:`repro.accel.pallas_interpret`): compiled on a TPU, the Pallas
@@ -32,11 +34,15 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ...accel import pallas_interpret
 from ...core.piece_selection import MAX_EXACT_AVAILABILITY
 from .kernel import (
+    LANES,
     rarest_argmin_call,
+    select_rows_call,
+    select_rows_vmem_bytes,
     waterfill_call,
     waterfill_vmem_bytes,
     waterfill_xla,
@@ -48,6 +54,11 @@ BLOCK_FLOWS = 128
 # scoped-VMEM budget of the water-fill kernel; larger tables run the XLA
 # fixed point (v5e has 128 MiB of VMEM, 16 MiB scoped by default)
 WATERFILL_VMEM_LIMIT = 16 << 20
+# the row select's scoped-VMEM limit; rows per grid step halve until its
+# buffers take at most three quarters of it
+SELECT_VMEM_LIMIT = 16 << 20
+# sublane rows of 128 pieces reduced at a time in the row select
+SELECT_CHUNK = 64
 
 
 def _next_pow2(x: int, lo: int = 0) -> int:
@@ -212,35 +223,76 @@ def fleet_waterfill(
 # --------------------------------------------------------------------------- device state
 
 
+class SelectPlan(NamedTuple):
+    """Row layout and grid of the device select, from the piece count."""
+
+    width: int  # sublane rows of 128 pieces per have/jitter row
+    rows: int  # selected rows copied and reduced per grid step
+    vmem_limit: int  # scoped VMEM of the call (bytes)
+
+
+def select_plan(P: int) -> SelectPlan:
+    """A state row holds ``width * 128 >= P`` pieces, ``width`` a multiple
+    of 8 so that each row is one run of whole ``(8, 128)`` tiles. Rows are
+    reduced in chunks of ``min(SELECT_CHUNK, width)`` sublanes. Rows per
+    step start at one row tile (128) and halve, down to 8, until two slots
+    of them and their accumulators fit three quarters of
+    :data:`SELECT_VMEM_LIMIT`; wider rows than that raise the limit
+    instead."""
+    width = -(-P // (8 * LANES)) * 8
+    chunk = min(SELECT_CHUNK, width)
+    rows = BLOCK_ROWS
+    while rows > 8 and select_rows_vmem_bytes(
+            width, rows, chunk) > SELECT_VMEM_LIMIT * 3 // 4:
+        rows //= 2
+    need = select_rows_vmem_bytes(width, rows, chunk)
+    return SelectPlan(width, rows, max(SELECT_VMEM_LIMIT, need * 4 // 3))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_rows(rows, block, start):
+    """Write a ``(b, P)`` block into rows ``start:start + b`` of an
+    ``(n, width, 128)`` array, zero past the last piece."""
+    b, P = block.shape
+    width = rows.shape[1]
+    block = jnp.pad(block, ((0, 0), (0, width * LANES - P)))
+    return lax.dynamic_update_slice(
+        rows, block.reshape(b, width, LANES), (start, 0, 0))
+
+
+def _as_rows(x: np.ndarray, width: int, dtype) -> jax.Array:
+    """A host ``(n, P)`` matrix as a device ``(n, width, 128)`` array, sent
+    in blocks of about 256 MB: padding it whole on the host costs seconds
+    at fleet size, and on the device two more copies of it."""
+    n, P = x.shape
+    out = jnp.zeros((n, width, LANES), dtype=dtype)
+    block = max(1, (256 << 20) // (P * np.dtype(dtype).itemsize))
+    for start in range(0, n, block):
+        out = _put_rows(out, np.asarray(x[start:start + block], dtype),
+                        np.int32(start))
+    return out
+
+
+def _select_rule(stream: str, mode: str, fallback: bool) -> str:
+    """``FleetSwarmSim._select``'s class rule for one stream."""
+    if stream != "http":
+        return "swarm_served"
+    if mode == "http_first":
+        return "any"
+    return "origin_or_unserved" if fallback else "origin"
+
+
 @functools.lru_cache(maxsize=None)
-def _select_jit(
-    stream_http: bool, http_first: bool, fallback: bool, bp: int,
-    interpret: bool,
-):
+def _select_jit(rule: str, interpret: bool):
     def fleet_select(have, jitter, repl, swarm_class, rows, other):
-        _, P = have.shape
-        miss = ~have[rows]  # (k, P) — built and consumed on device
-        if stream_http:
-            if http_first:
-                cand = miss
-            else:
-                cand = miss & ~swarm_class[None, :]
-                if fallback:
-                    # origin rescue for swarm-routed pieces nobody serves
-                    cand = cand | (
-                        miss & swarm_class[None, :] & (repl == 0)[None, :]
-                    )
-        else:
-            cand = miss & swarm_class[None, :] & (repl > 0)[None, :]
-        # a peer's two streams exclude each other's current piece
-        pid = jnp.arange(P, dtype=other.dtype)[None, :]
-        cand = cand & ~((other[:, None] >= 0) & (pid == other[:, None]))
-        pad = (0, -(-P // bp) * bp - P)
-        return rarest_argmin_call(
-            jnp.pad(cand, ((0, 0), pad)),
-            jnp.pad(repl.astype(jnp.float32), pad),
-            jnp.pad(jitter[rows], ((0, 0), pad)),
-            block_rows=BLOCK_ROWS, block_pieces=bp, interpret=interpret,
+        P = repl.shape[0]
+        plan = select_plan(P)
+        repl = jnp.pad(repl, (0, plan.width * LANES - P))
+        return select_rows_call(
+            have, jitter, repl.reshape(plan.width, LANES), swarm_class,
+            rows, other, n_pieces=P, rule=rule, rows_per_step=plan.rows,
+            chunk=SELECT_CHUNK, vmem_limit_bytes=plan.vmem_limit,
+            interpret=interpret,
         )
 
     return jax.jit(fleet_select)
@@ -250,29 +302,32 @@ def _select_jit(
 def fleet_add_pieces(have, repl, rows, pieces):
     # out-of-bounds padding indices are dropped, so one trace serves
     # every power-of-two batch size
-    have = have.at[rows, pieces].set(True, mode="drop")
+    have = have.at[rows, pieces // LANES, pieces % LANES].set(
+        1, mode="drop")
     repl = repl.at[pieces].add(1, mode="drop")
     return have, repl
 
 
 @jax.jit
 def fleet_drop_rows(have, repl, rows):
-    got = have.at[rows].get(mode="fill", fill_value=False)
-    return repl - got.sum(axis=0).astype(repl.dtype)
+    got = have.at[rows].get(mode="fill", fill_value=0)
+    held = got.sum(axis=0, dtype=repl.dtype).reshape(-1)
+    return repl - held[: repl.shape[0]]
 
 
 class FleetDeviceState:
     """Device-resident tick state for ``FleetSpec.backend="pallas"``.
 
-    Holds the have-matrix, fixed jitter, replica counts, and the static
-    swarm-routing class on device across ticks. The engine keeps its numpy
-    mirrors for scalar control flow (leech masks, host-RNG source
-    sampling); the ``O(n * P)`` candidate-mask + argmin traffic — the
-    fleet tick's dominant term — happens here, and only ``(k,)`` pick
-    vectors cross back per call. Water-filling runs on the device too;
-    ``waterfill_runs`` counts the calls per implementation, and
-    ``peak_flows`` / ``rounds`` record the largest flow table and the
-    fixed-point rounds summed over the run.
+    Holds the have matrix (0/1 uint8), fixed jitter, replica counts, and
+    the static swarm-routing class on device across ticks; have and jitter
+    in the row-contiguous layout of :func:`select_plan`. The engine keeps
+    its numpy mirrors for scalar control flow (leech masks, host-RNG source
+    sampling); the ``O(k * P)`` candidate-mask + argmin traffic — the
+    fleet tick's dominant term — happens here, reading only the selected
+    rows, and only ``(k,)`` pick vectors cross back per call.
+    Water-filling runs on the device too; ``waterfill_runs`` counts the
+    calls per implementation, and ``peak_flows`` / ``rounds`` record the
+    largest flow table and the fixed-point rounds summed over the run.
     """
 
     def __init__(self, jitter: np.ndarray, swarm_class: np.ndarray,
@@ -283,14 +338,20 @@ class FleetDeviceState:
         )
         self.n, self.P = n, P
         self.interpret = _resolve_interpret(interpret)
-        self.have = jnp.zeros((n, P), dtype=bool)
-        self.jitter = jnp.asarray(jitter, dtype=jnp.float32)
+        width = select_plan(P).width
+        self.have_rows = jnp.zeros((n, width, LANES), dtype=jnp.uint8)
+        self.jitter_rows = _as_rows(jitter, width, np.float32)
         self.repl = jnp.zeros(P, dtype=jnp.int32)
-        self.swarm_class = jnp.asarray(swarm_class, dtype=bool)
-        self.bp = _piece_block(P)
+        self.class_rows = _as_rows(
+            np.asarray(swarm_class)[None], width, np.int32)[0]
         self.waterfill_runs = {"pallas": 0, "xla": 0}
         self.peak_flows = 0
         self.rounds = 0
+
+    @property
+    def have(self) -> np.ndarray:
+        """The ``(n, P)`` 0/1 have matrix (uint8), copied to the host."""
+        return np.asarray(self.have_rows).reshape(self.n, -1)[:, : self.P]
 
     def select(self, rows: np.ndarray, other: np.ndarray, *,
                stream: str, mode: str, fallback: bool) -> np.ndarray:
@@ -301,16 +362,13 @@ class FleetDeviceState:
         """
         k = rows.size
         kp = _next_pow2(k, 7)  # whole row tiles; pow2 bounds retraces
-        rows_p = np.zeros(kp, dtype=np.int32)
+        rows_p = np.full(kp, -1, dtype=np.int32)  # -1: nothing to copy
         rows_p[:k] = rows
         other_p = np.full(kp, -1, dtype=np.int32)
         other_p[:k] = other
-        fn = _select_jit(
-            stream == "http", mode == "http_first", bool(fallback),
-            self.bp, self.interpret,
-        )
+        fn = _select_jit(_select_rule(stream, mode, fallback), self.interpret)
         out = fn(
-            self.have, self.jitter, self.repl, self.swarm_class,
+            self.have_rows, self.jitter_rows, self.repl, self.class_rows,
             rows_p, other_p,
         )
         return np.asarray(out)[:k].astype(np.int64)
@@ -324,7 +382,8 @@ class FleetDeviceState:
         p = np.full(kp, self.P, dtype=np.int32)
         r[:k] = rows
         p[:k] = pieces
-        self.have, self.repl = fleet_add_pieces(self.have, self.repl, r, p)
+        self.have_rows, self.repl = fleet_add_pieces(
+            self.have_rows, self.repl, r, p)
 
     def drop_rows(self, rows: np.ndarray) -> None:
         """Departures: remove the rows' held pieces from the replica
@@ -333,7 +392,7 @@ class FleetDeviceState:
         kp = _next_pow2(k, 3)
         r = np.full(kp, self.n, dtype=np.int32)  # OOB gather -> fill False
         r[:k] = rows
-        self.repl = fleet_drop_rows(self.have, self.repl, r)
+        self.repl = fleet_drop_rows(self.have_rows, self.repl, r)
 
     def waterfill(self, src, dst, up_cap, down_cap, link_of, link_cap):
         """:func:`fleet_waterfill` on the device, keeping the run's

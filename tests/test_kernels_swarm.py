@@ -23,6 +23,8 @@ import pytest
 
 from repro.core.fleet import waterfill_rates
 from repro.core.piece_selection import batched_rarest
+from repro.kernels.swarm import kernel as swarm_kernel
+from repro.kernels.swarm import ops as swarm_ops
 from repro.kernels.swarm import (
     FleetDeviceState,
     fleet_waterfill,
@@ -230,28 +232,9 @@ def test_device_select_compiles_once_per_row_bucket():
     ]) == 0
 
 
-@pytest.mark.parametrize("stream,mode,fallback", [
-    ("http", "swarm_first", True),
-    ("http", "swarm_first", False),
-    ("http", "http_first", False),
-    ("swarm", "swarm_first", True),
-])
-def test_device_select_matches_engine_cand_build(stream, mode, fallback):
-    n, P = 60, 45
-    jitter = RNG.random((n, P), dtype=np.float32)
-    swarm_class = RNG.random(P) < 0.6
-    dev = FleetDeviceState(jitter, swarm_class)
-    flat = np.unique(RNG.integers(0, n * P, 200))  # unique (row, piece)
-    have_rows, have_pieces = flat // P, flat % P
-    dev.add_pieces(have_rows, have_pieces)
-    have = np.zeros((n, P), dtype=bool)
-    have[have_rows, have_pieces] = True
-    repl = have.sum(axis=0)
-
-    rows = np.unique(RNG.integers(0, n, 20))
-    other = np.where(RNG.random(rows.size) < 0.5,
-                     RNG.integers(0, P, rows.size), -1)
-    # the engine's numpy cand build (FleetSwarmSim._select)
+def _engine_cand(have, repl, swarm_class, rows, other, stream, mode,
+                 fallback):
+    """The engine's numpy cand build (FleetSwarmSim._select)."""
     missing = ~have[rows]
     if stream == "http":
         if mode == "http_first":
@@ -264,10 +247,112 @@ def test_device_select_matches_engine_cand_build(stream, mode, fallback):
         cand = missing & swarm_class[None, :] & (repl > 0)[None, :]
     has_other = other >= 0
     cand[np.flatnonzero(has_other), other[has_other]] = False
-    np.testing.assert_array_equal(
-        dev.select(rows, other, stream=stream, mode=mode, fallback=fallback),
-        batched_rarest(cand, repl, jitter[rows]),
-    )
+    return cand
+
+
+HTTP_FALLBACK = ("http", "swarm_first", True)
+HTTP_ORIGIN = ("http", "swarm_first", False)
+HTTP_FIRST = ("http", "http_first", False)
+SWARM = ("swarm", "swarm_first", True)
+
+
+@pytest.mark.parametrize("stream,mode,fallback,k,P,ties,chunk", [
+    # 20 distinct rows of 45 pieces, one case per class rule
+    pytest.param(*HTTP_FALLBACK, 20, 45, False, None,
+                 id="http-swarm_first-True"),
+    pytest.param(*HTTP_ORIGIN, 20, 45, False, None,
+                 id="http-swarm_first-False"),
+    pytest.param(*HTTP_FIRST, 20, 45, False, None,
+                 id="http-http_first-False"),
+    pytest.param(*SWARM, 20, 45, False, None, id="swarm-swarm_first-True"),
+    # row counts around the 128-row bucket and the rows per grid step
+    # (128 at these widths), with duplicated rows and all-masked rows
+    pytest.param(*SWARM, 1, 125, False, None, id="k1-P125"),
+    pytest.param(*HTTP_FALLBACK, 127, 128, False, None, id="k127-P128"),
+    pytest.param(*HTTP_FIRST, 128, 300, True, None, id="k128-P300-ties"),
+    pytest.param(*HTTP_ORIGIN, 129, 45, True, None, id="k129-P45-ties"),
+    # rows wider than one reduction chunk (64 x 128 pieces): one chunk
+    # and a tail at 64 rows a step; exact (availability, jitter) ties
+    # across chunks and row groups
+    pytest.param(*HTTP_FIRST, 45, 9000, True, None, id="k45-P9000-ties"),
+    # the same merges on narrower rows, with the kernel's chunk and 8 rows
+    # a step given: three whole chunks of 8 sublanes, then a chunk of 16
+    # and a tail of 8
+    pytest.param(*SWARM, 200, 3000, True, 8, id="k200-P3000-chunk8-ties"),
+    pytest.param(*HTTP_FALLBACK, 129, 2100, True, 16,
+                 id="k129-P2100-chunk16-ties"),
+])
+def test_device_select_matches_engine_cand_build(stream, mode, fallback, k,
+                                                 P, ties, chunk):
+    n = 60
+    swarm_class = RNG.random(P) < 0.6
+    if ties:
+        # rows 0 and 1 hold every piece and the other even rows the first
+        # half, so the second half's pieces all have two replicas; with
+        # two jitter values, exact ties run across chunks and picks land
+        # past the first chunk
+        jitter = (RNG.integers(0, 2, (n, P)) / 2).astype(np.float32)
+        half = np.arange(2, n, 2)[:, None] * P + np.arange(P // 2)
+        flat = np.union1d(np.arange(2 * P), half)
+    else:
+        jitter = RNG.random((n, P), dtype=np.float32)
+        flat = np.unique(RNG.integers(0, n * P, 4 * P))  # unique pairs
+        flat = np.union1d(flat, np.arange(2 * P))  # rows 0, 1 hold all
+    dev = FleetDeviceState(jitter, swarm_class)
+    have_rows, have_pieces = flat // P, flat % P
+    dev.add_pieces(have_rows, have_pieces)
+    have = np.zeros((n, P), dtype=bool)
+    have[have_rows, have_pieces] = True
+    repl = have.sum(axis=0)
+    np.testing.assert_array_equal(dev.have, have)
+
+    if k == 20:
+        rows = np.unique(RNG.integers(0, n, k))
+    else:  # with repeats, and the all-masked rows 0 and 1 among them
+        rows = RNG.integers(0, n, k)
+        rows[: min(k, 4)] = [0, 1, 0, 1][: min(k, 4)]
+    # the other stream's piece: for a third of the rows the pick this
+    # stream would make without it, for a third any piece, else none
+    none = np.full(rows.size, -1)
+    first = batched_rarest(
+        _engine_cand(have, repl, swarm_class, rows, none, stream, mode,
+                     fallback), repl, jitter[rows])
+    draw = RNG.integers(0, 3, rows.size)
+    other = np.select([draw == 0, draw == 1],
+                      [first, RNG.integers(0, P, rows.size)], -1)
+    cand = _engine_cand(have, repl, swarm_class, rows, other, stream, mode,
+                        fallback)
+    want = batched_rarest(cand, repl, jitter[rows])
+    # the whole padded bucket, whose padding rows pick nothing: the select
+    # program, or the kernel at the case's chunk and 8 rows a step
+    kp = 1 << max(7, (rows.size - 1).bit_length())
+    rows_p = np.full(kp, -1, dtype=np.int32)
+    rows_p[: rows.size] = rows
+    other_p = np.full(kp, -1, dtype=np.int32)
+    other_p[: rows.size] = other
+    rule = swarm_ops._select_rule(stream, mode, fallback)
+    if chunk is None:
+        full = swarm_ops._select_jit(rule, dev.interpret)(
+            dev.have_rows, dev.jitter_rows, dev.repl, dev.class_rows,
+            rows_p, other_p)
+    else:
+        plan = swarm_ops.select_plan(P)
+        repl_p = np.zeros(plan.width * 128, dtype=np.int32)
+        repl_p[:P] = repl
+        full = swarm_kernel.select_rows_call(
+            dev.have_rows, dev.jitter_rows, repl_p.reshape(plan.width, 128),
+            dev.class_rows, rows_p, other_p, n_pieces=P, rule=rule,
+            rows_per_step=8, chunk=chunk,
+            vmem_limit_bytes=plan.vmem_limit, interpret=dev.interpret)
+    full = np.asarray(full)
+    np.testing.assert_array_equal(full[: rows.size], want)
+    assert (full[rows.size:] == -1).all()
+    if k > 20:
+        assert (want[np.isin(rows, [0, 1])] == -1).all()
+    if chunk is None:  # the same program, through the engine's entry point
+        np.testing.assert_array_equal(
+            dev.select(rows, other, stream=stream, mode=mode,
+                       fallback=fallback), want)
 
 
 # ------------------------------------------------------------------ engine parity

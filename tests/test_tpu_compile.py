@@ -11,6 +11,8 @@ process at a time may load the TPU library.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +53,7 @@ def _compile(fn, shapes, sharding):
 
 
 @pytest.mark.parametrize("k,P,bp", [
-    (131072, 128, 128),  # the 100k-client crowd's padded select
+    (131072, 128, 128),  # the largest row bucket, one piece tile
     (1024, 1024, 256),   # several piece tiles
 ])
 def test_rarest_argmin_compiles(one_chip, k, P, bp):
@@ -66,16 +68,52 @@ def test_rarest_argmin_compiles(one_chip, k, P, bp):
     assert "tpu_custom_call" in hlo
 
 
+def _select_lowered(n, P, k, sharding):
+    """The device select program for ``n`` clients of ``P`` pieces and a
+    ``k``-row bucket (the HTTP stream's rule with the origin rescue)."""
+    width = swarm_ops.select_plan(P).width
+    fn = swarm_ops._select_jit("origin_or_unserved", False)
+    shapes = [((n, width, 128), jnp.uint8), ((n, width, 128), jnp.float32),
+              ((P,), jnp.int32), ((width, 128), jnp.int32),
+              ((k,), jnp.int32), ((k,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return fn.lower(*args).compile()
+
+
+def _hlo_results(hlo):
+    """(opcode, dims) of every instruction in an HLO text dump."""
+    inst = re.compile(r"=\s*\w+\[([\d,]*)\][^ ]*\s+([\w-]+)\(")
+    for line in hlo.splitlines():
+        m = inst.search(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(1).split(",") if d)
+            yield m.group(2), dims
+
+
 def test_fleet_select_compiles_at_100k(one_chip):
-    n, P, k = 100_000, 125, 131072
-    fn = swarm_ops._select_jit(False, False, True, swarm_ops._piece_block(P),
-                               False)
-    hlo = _compile(
-        fn, [((n, P), jnp.bool_), ((n, P), jnp.float32), ((P,), jnp.int32),
-             ((P,), jnp.bool_), ((k,), jnp.int32), ((k,), jnp.int32)],
-        one_chip,
-    )
+    # the 100k-client crowd's largest bucket: its rows and picks go
+    # through SMEM a grid step at a time, never all at once
+    hlo = _select_lowered(100_000, 125, 131072, one_chip).as_text()
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("k", [2048, 16384])
+def test_fleet_select_reads_rows_in_place(one_chip, k):
+    # the ImageNet crowd: 16,384 clients of 37,504 pieces. The program
+    # copies neither matrix nor gathers or pads (k, P) rows: besides its
+    # parameters, no result holds more than one value per row or one per
+    # (padded) piece, and its temporaries stay small
+    n, P = 16384, 37504
+    compiled = _select_lowered(n, P, k, one_chip)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    per_piece = swarm_ops.select_plan(P).width * 128
+    for op, dims in _hlo_results(hlo):
+        if op == "parameter":
+            continue
+        assert "gather" not in op, (op, dims)
+        assert math.prod(dims) <= max(k, per_piece), (op, dims)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def _waterfill_hlo(plan, sharding):
