@@ -62,7 +62,10 @@ operations:
   before that decision lie outside it, as do a ``run`` call's last pass
   (which stops at ``until``) and idle fast-forwards;
 * ``fleet.select`` — one selection call on one stream
-  (``phase_seconds["select"]``);
+  (``phase_seconds["select"]``); where several chips share the device
+  state, its metadata ``rows`` holds the rows selected,
+  ``shard_rows_max`` those on the busiest chip and ``devices`` the
+  chips;
 * ``fleet.resample`` — sampling the source tables of a set of rows;
 * ``fleet.flow_table`` — HTTP admission and the flow table, choking
   included;
@@ -292,6 +295,14 @@ class FleetSpec:
       jitter held on device across ticks. Raises where the installed jax
       has no Pallas; it never degrades to another backend.
 
+    ``devices``: chips that share the device state on ``"pallas"``, the
+    first ``devices`` of ``jax.devices()``. Their mesh splits the client
+    rows (have matrix and jitter) into contiguous blocks, one a chip; the
+    replica counts are copied to every chip and the water-fill runs on
+    the first. Picks, rates and state are the same for any count. More
+    than 1 needs ``backend="pallas"``; ``ScenarioSpec.build("fleet")``
+    refuses more than JAX sees.
+
     ``None`` normalizes from the deprecated ``jit`` flag (``True`` ->
     ``"jit"``, else ``"numpy"``); after ``__post_init__`` the two fields
     are always consistent (``jit == (backend == "jit")``).
@@ -301,12 +312,16 @@ class FleetSpec:
     fanout: Optional[int] = None
     jit: bool = False
     backend: Optional[str] = None
+    devices: int = 1
 
     def __post_init__(self) -> None:
         if self.dt is not None and self.dt <= 0:
             raise ValueError("fleet dt must be positive (or None for auto)")
         if self.fanout is not None and self.fanout < 1:
             raise ValueError("fleet fanout must be >= 1 (or None for auto)")
+        if self.devices < 1:
+            raise ValueError(
+                f"fleet devices must be >= 1 (got {self.devices})")
         if self.backend is None:
             if self.jit:
                 warnings.warn(
@@ -324,6 +339,11 @@ class FleetSpec:
                 f"deprecated jit=True conflicts with backend={self.backend!r}"
             )
         self.jit = self.backend == "jit"
+        if self.devices > 1 and self.backend != "pallas":
+            raise ValueError(
+                f"fleet devices={self.devices} needs backend='pallas' (the "
+                f"{self.backend!r} backend keeps its state on the host)"
+            )
 
     def to_dict(self) -> dict:
         return spec_to_dict(self)
@@ -624,7 +644,8 @@ class FleetSwarmSim:
         if self.fleet_cfg.backend == "pallas":
             from ..kernels.swarm import FleetDeviceState
 
-            self.device = FleetDeviceState(self.jitter, self.swarm_class)
+            self.device = FleetDeviceState(
+                self.jitter, self.swarm_class, devices=self.fleet_cfg.devices)
         # wall-clock per phase across the whole run (run.py --profile)
         self.phase_seconds = {
             "select": 0.0, "waterfill": 0.0,
@@ -691,7 +712,7 @@ class FleetSwarmSim:
             return
         if stream == "http" and not live_mirror:
             return
-        with Span("fleet.select", self.phase_seconds, "select"):
+        with Span("fleet.select", self.phase_seconds, "select") as span:
             other = (
                 self.cur_swarm[rows] if stream == "http"
                 else self.cur_http[rows]
@@ -704,6 +725,12 @@ class FleetSwarmSim:
                     mode=self.policy.mode,
                     fallback=self.policy.http_fallback,
                 )
+                if self.device.devices > 1:
+                    span.set_metadata(
+                        rows=rows.size,
+                        shard_rows_max=self.device.shard_rows_max,
+                        devices=self.device.devices,
+                    )
             else:
                 missing = ~self.have[rows]
                 if stream == "http":

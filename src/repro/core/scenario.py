@@ -1279,6 +1279,16 @@ class ScenarioSpec:
                     f"{ev.kind} events are object-engine only (the fleet "
                     "engine models churn through seed_linger)"
                 )
+        devices = self.fleet.devices if self.fleet is not None else 1
+        if devices > 1:
+            import jax
+
+            seen = jax.device_count()
+            if devices > seen:
+                raise ValueError(
+                    f"fleet devices={devices} but JAX sees {seen} "
+                    f"{jax.default_backend()} device(s)"
+                )
         man = self.content.manifests[0]
         mi, _ = man.build()   # payload bytes unused: fluid pools only
         tel = self.telemetry

@@ -19,7 +19,9 @@ Three layers, mirroring the checksum/attention packages:
   scatter updates sized by the number of finished pieces, not by
   ``n * P``. Padding rows use out-of-bounds indices, which jax scatter
   semantics drop (``mode="drop"`` made explicit below), so variable-size
-  updates reuse a handful of power-of-two traces.
+  updates reuse a handful of power-of-two traces. Its programs run on
+  every chip of a 1-D mesh of ``devices`` chips (one chip included), each
+  on its own block of client rows.
 
 ``interpret=None`` resolves per platform
 (:func:`repro.accel.pallas_interpret`): compiled on a TPU, the Pallas
@@ -35,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as PS
 
 from ...accel import pallas_interpret
 from ...core.piece_selection import MAX_EXACT_AVAILABILITY
@@ -59,6 +63,8 @@ WATERFILL_VMEM_LIMIT = 16 << 20
 SELECT_VMEM_LIMIT = 16 << 20
 # sublane rows of 128 pieces reduced at a time in the row select
 SELECT_CHUNK = 64
+# the device state's mesh axis: chips share the client rows
+PEERS = "peers"
 
 
 def _next_pow2(x: int, lo: int = 0) -> int:
@@ -260,17 +266,37 @@ def _put_rows(rows, block, start):
         rows, block.reshape(b, width, LANES), (start, 0, 0))
 
 
-def _as_rows(x: np.ndarray, width: int, dtype) -> jax.Array:
-    """A host ``(n, P)`` matrix as a device ``(n, width, 128)`` array, sent
-    in blocks of about 256 MB: padding it whole on the host costs seconds
-    at fleet size, and on the device two more copies of it."""
+def _zeros(shape, dtype, sharding) -> jax.Array:
+    """Zeros made where ``sharding`` puts them, each device writing its
+    own shard (``jnp.zeros(device=...)`` fills one device's shard and
+    copies it to the others)."""
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)()
+
+
+def _as_rows(x: np.ndarray, width: int, dtype, mesh: Mesh) -> jax.Array:
+    """A host ``(n, P)`` matrix as a device ``(n_pad, width, 128)`` array
+    sharded by contiguous row blocks over ``mesh`` (``n_pad`` the next
+    multiple of its size, zero rows past ``n``). Each chip's block is
+    built on that chip, sent in pieces of about 256 MB: padding the matrix
+    whole on the host costs seconds at fleet size, and on a device two
+    more copies of it."""
     n, P = x.shape
-    out = jnp.zeros((n, width, LANES), dtype=dtype)
+    devs = mesh.devices.ravel()
+    per = -(-n // devs.size)
     block = max(1, (256 << 20) // (P * np.dtype(dtype).itemsize))
-    for start in range(0, n, block):
-        out = _put_rows(out, np.asarray(x[start:start + block], dtype),
-                        np.int32(start))
-    return out
+    shards = [_zeros((per, width, LANES), dtype, SingleDeviceSharding(d))
+              for d in devs]
+    # round-robin over the chips, so that their copies overlap
+    for off in range(0, per, block):
+        for i in range(devs.size):
+            lo = i * per + off
+            hi = min(n, i * per + min(per, off + block))
+            if lo < hi:
+                shards[i] = _put_rows(shards[i], np.asarray(x[lo:hi], dtype),
+                                      np.int32(off))
+    return jax.make_array_from_single_device_arrays(
+        (per * devs.size, width, LANES), NamedSharding(mesh, PS(PEERS)),
+        shards)
 
 
 def _select_rule(stream: str, mode: str, fallback: bool) -> str:
@@ -282,8 +308,39 @@ def _select_rule(stream: str, mode: str, fallback: bool) -> str:
     return "origin_or_unserved" if fallback else "origin"
 
 
+def _local_rows(rows, per):
+    """Global row indices as this chip's own (``per`` rows a chip), with
+    rows another chip owns out of bounds (``per``)."""
+    local = rows - lax.axis_index(PEERS) * per
+    return jnp.where((local >= 0) & (local < per), local, per)
+
+
+def _mesh_jit(fn, mesh: Mesh, in_specs, out_specs, donate=()):
+    """``jax.jit`` of ``fn`` run on every chip of ``mesh``, each on its own
+    blocks (``in_specs`` / ``out_specs`` over :data:`PEERS`), donating
+    the arguments ``donate``."""
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+
+    @functools.wraps(fn)  # a profile shows each program by name
+    def program(*args):
+        return mapped(*args)
+
+    def shardings(specs):
+        return jax.tree.map(lambda spec: NamedSharding(mesh, spec), specs)
+
+    return jax.jit(program, in_shardings=shardings(in_specs),
+                   out_shardings=shardings(out_specs), donate_argnums=donate)
+
+
+# The programs below run one body per chip of a 1-D ``("peers",)`` mesh,
+# each on its own block of rows. Named functions, not partials: a profile
+# shows each program by name.
+ROW, WHOLE = PS(PEERS), PS()
+
+
 @functools.lru_cache(maxsize=None)
-def _select_jit(rule: str, interpret: bool):
+def _select_jit(rule: str, interpret: bool, mesh: Mesh):
     def fleet_select(have, jitter, repl, swarm_class, rows, other):
         P = repl.shape[0]
         plan = select_plan(P)
@@ -295,24 +352,42 @@ def _select_jit(rule: str, interpret: bool):
             interpret=interpret,
         )
 
-    return jax.jit(fleet_select)
+    return _mesh_jit(fleet_select, mesh, (ROW, ROW, WHOLE, WHOLE, ROW, ROW),
+                     ROW)
 
 
-@jax.jit
-def fleet_add_pieces(have, repl, rows, pieces):
-    # out-of-bounds padding indices are dropped, so one trace serves
-    # every power-of-two batch size
-    have = have.at[rows, pieces // LANES, pieces % LANES].set(
-        1, mode="drop")
-    repl = repl.at[pieces].add(1, mode="drop")
-    return have, repl
+@functools.lru_cache(maxsize=None)
+def _add_pieces_jit(mesh: Mesh):
+    def fleet_add_pieces(have, repl, rows, pieces):
+        # every chip gets the whole list: it writes the rows it owns, and
+        # adds every piece to its own copy of the replica counts, so the
+        # copies stay equal with no collective. Out-of-bounds padding
+        # indices are dropped, so one trace serves every power-of-two
+        # batch size
+        rows = _local_rows(rows, have.shape[0])
+        have = have.at[rows, pieces // LANES, pieces % LANES].set(
+            1, mode="drop")
+        repl = repl.at[pieces].add(1, mode="drop")
+        return have, repl
+
+    # over several chips the have matrix is updated in place: the warm-
+    # up's queued calls would each hold a copy of a chip's rows. One chip
+    # keeps its copy (ROADMAP P4: donating there moves the one-chip cell)
+    return _mesh_jit(fleet_add_pieces, mesh, (ROW, WHOLE, WHOLE, WHOLE),
+                     (ROW, WHOLE), donate=(0,) if mesh.size > 1 else ())
 
 
-@jax.jit
-def fleet_drop_rows(have, repl, rows):
-    got = have.at[rows].get(mode="fill", fill_value=0)
-    held = got.sum(axis=0, dtype=repl.dtype).reshape(-1)
-    return repl - held[: repl.shape[0]]
+@functools.lru_cache(maxsize=None)
+def _drop_rows_jit(mesh: Mesh):
+    def fleet_drop_rows(have, repl, rows):
+        got = have.at[_local_rows(rows, have.shape[0])].get(
+            mode="fill", fill_value=0)
+        held = got.sum(axis=0, dtype=repl.dtype).reshape(-1)
+        # each chip sums its own rows: the one collective
+        held = lax.psum(held, PEERS)
+        return repl - held[: repl.shape[0]]
+
+    return _mesh_jit(fleet_drop_rows, mesh, (ROW, WHOLE, WHOLE), WHOLE)
 
 
 class FleetDeviceState:
@@ -325,25 +400,47 @@ class FleetDeviceState:
     sampling); the ``O(k * P)`` candidate-mask + argmin traffic — the
     fleet tick's dominant term — happens here, reading only the selected
     rows, and only ``(k,)`` pick vectors cross back per call.
-    Water-filling runs on the device too; ``waterfill_runs`` counts the
-    calls per implementation, and ``peak_flows`` / ``rounds`` record the
-    largest flow table and the fixed-point rounds summed over the run.
+
+    ``devices`` chips (the first of ``jax.devices()``, a 1-D mesh on
+    :data:`PEERS`) share the client rows: have and jitter are split into
+    contiguous blocks of ``rows_per_device`` rows (``n`` padded up to a
+    multiple of ``devices``), the replica counts and the class vector are
+    copied to every chip. A selection sends each chip its own rows, in one
+    bucket sized by the busiest chip (``shard_rows_max`` keeps the last
+    call's count); completions go to every chip, each writing the rows it
+    owns and adding all of them to its replica counts; departures sum each
+    chip's rows and add the sums over the mesh. Water-filling runs on the
+    mesh's first chip; ``waterfill_runs`` counts the calls per implementation, and
+    ``peak_flows`` / ``rounds`` record the largest flow table and the
+    fixed-point rounds summed over the run.
     """
 
     def __init__(self, jitter: np.ndarray, swarm_class: np.ndarray,
-                 *, interpret=None) -> None:
+                 *, devices: int = 1, interpret=None) -> None:
         n, P = jitter.shape
         assert n < MAX_EXACT_AVAILABILITY, (
             "replica counts no longer exact in float32 — fleet too large"
         )
+        have_devs = jax.devices()
+        if not 1 <= devices <= len(have_devs):
+            raise ValueError(f"the device state wants {devices} devices; "
+                             f"JAX sees {len(have_devs)}")
         self.n, self.P = n, P
+        self.devices = devices
+        self.rows_per_device = -(-n // devices)
+        self.n_pad = self.rows_per_device * devices
+        self.mesh = Mesh(np.array(have_devs[:devices]), (PEERS,))
         self.interpret = _resolve_interpret(interpret)
         width = select_plan(P).width
-        self.have_rows = jnp.zeros((n, width, LANES), dtype=jnp.uint8)
-        self.jitter_rows = _as_rows(jitter, width, np.float32)
-        self.repl = jnp.zeros(P, dtype=jnp.int32)
-        self.class_rows = _as_rows(
-            np.asarray(swarm_class)[None], width, np.int32)[0]
+        self.have_rows = _zeros((self.n_pad, width, LANES), jnp.uint8,
+                                NamedSharding(self.mesh, PS(PEERS)))
+        self.jitter_rows = _as_rows(jitter, width, np.float32, self.mesh)
+        whole = NamedSharding(self.mesh, PS())
+        self.repl = jax.device_put(np.zeros(P, dtype=np.int32), whole)
+        cls = np.zeros(width * LANES, dtype=np.int32)
+        cls[:P] = swarm_class
+        self.class_rows = jax.device_put(cls.reshape(width, LANES), whole)
+        self.shard_rows_max = 0
         self.waterfill_runs = {"pallas": 0, "xla": 0}
         self.peak_flows = 0
         self.rounds = 0
@@ -351,7 +448,8 @@ class FleetDeviceState:
     @property
     def have(self) -> np.ndarray:
         """The ``(n, P)`` 0/1 have matrix (uint8), copied to the host."""
-        return np.asarray(self.have_rows).reshape(self.n, -1)[:, : self.P]
+        return np.asarray(self.have_rows).reshape(
+            self.n_pad, -1)[: self.n, : self.P]
 
     def select(self, rows: np.ndarray, other: np.ndarray, *,
                stream: str, mode: str, fallback: bool) -> np.ndarray:
@@ -361,38 +459,53 @@ class FleetDeviceState:
         parity is pinned by the engine-equivalence test).
         """
         k = rows.size
-        kp = _next_pow2(k, 7)  # whole row tiles; pow2 bounds retraces
-        rows_p = np.full(kp, -1, dtype=np.int32)  # -1: nothing to copy
-        rows_p[:k] = rows
-        other_p = np.full(kp, -1, dtype=np.int32)
-        other_p[:k] = other
-        fn = _select_jit(_select_rule(stream, mode, fallback), self.interpret)
-        out = fn(
+        owner = rows // self.rows_per_device
+        counts = np.bincount(owner, minlength=self.devices)
+        self.shard_rows_max = int(counts.max(initial=0))
+        # whole row tiles; pow2 bounds retraces
+        kb = _next_pow2(self.shard_rows_max, 7)
+        # chip by chip, in call order within each: flat slots of the
+        # (devices, kb) bucket, padded with -1 (nothing to copy)
+        order = np.argsort(owner, kind="stable")
+        owner = owner[order]
+        at = owner * kb + np.arange(k) - np.repeat(
+            np.cumsum(counts) - counts, counts)
+        rows_p = np.full(self.devices * kb, -1, dtype=np.int32)
+        rows_p[at] = rows[order] - owner * self.rows_per_device
+        other_p = np.full(self.devices * kb, -1, dtype=np.int32)
+        other_p[at] = other[order]
+        fn = _select_jit(_select_rule(stream, mode, fallback), self.interpret,
+                         self.mesh)
+        out = np.asarray(fn(
             self.have_rows, self.jitter_rows, self.repl, self.class_rows,
             rows_p, other_p,
-        )
-        return np.asarray(out)[:k].astype(np.int64)
+        ))
+        pick = np.empty(k, dtype=np.int64)
+        pick[order] = out[at]
+        return pick
+
+    def _padded(self, rows: np.ndarray) -> tuple[np.ndarray, int]:
+        """``rows`` padded to a power of two with ``n_pad``, a row no chip
+        owns."""
+        k = rows.size
+        r = np.full(_next_pow2(k, 3), self.n_pad, dtype=np.int32)
+        r[:k] = rows
+        return r, k
 
     def add_pieces(self, rows: np.ndarray, pieces: np.ndarray) -> None:
         """Piece completions: scatter ``have[rows, pieces] = True`` and
         bump replica counts (padded with out-of-bounds drops)."""
-        k = rows.size
-        kp = _next_pow2(k, 3)
-        r = np.full(kp, self.n, dtype=np.int32)
-        p = np.full(kp, self.P, dtype=np.int32)
-        r[:k] = rows
+        r, k = self._padded(rows)
+        p = np.full(r.size, self.P, dtype=np.int32)
         p[:k] = pieces
-        self.have_rows, self.repl = fleet_add_pieces(
+        self.have_rows, self.repl = _add_pieces_jit(self.mesh)(
             self.have_rows, self.repl, r, p)
 
     def drop_rows(self, rows: np.ndarray) -> None:
         """Departures: remove the rows' held pieces from the replica
         counts (the have rows themselves stay, as on the host)."""
-        k = rows.size
-        kp = _next_pow2(k, 3)
-        r = np.full(kp, self.n, dtype=np.int32)  # OOB gather -> fill False
-        r[:k] = rows
-        self.repl = fleet_drop_rows(self.have_rows, self.repl, r)
+        r, _ = self._padded(rows)  # out-of-bounds gather -> fill 0
+        self.repl = _drop_rows_jit(self.mesh)(self.have_rows, self.repl, r)
 
     def waterfill(self, src, dst, up_cap, down_cap, link_of, link_cap):
         """:func:`fleet_waterfill` on the device, keeping the run's

@@ -313,6 +313,49 @@ def test_scenario_fleet_block_roundtrip():
     assert again.fleet == spec.fleet
 
 
+def test_fleet_spec_devices_round_trip():
+    spec = FleetSpec(dt=1.0, backend="pallas", devices=4)
+    assert spec.to_dict()["devices"] == 4
+    assert FleetSpec.from_dict(spec.to_dict()) == spec
+    # pre-devices dicts still load, on one chip
+    old = FleetSpec.from_dict({"dt": 1.0, "fanout": None, "jit": False,
+                               "backend": "pallas"})
+    assert old.devices == 1
+    with pytest.raises(ValueError, match="unknown keys.*device"):
+        FleetSpec.from_dict({"backend": "pallas", "device": 4})
+    d = ScenarioSpec.load(f"{SCENARIOS}/fleet_scaling.json").to_dict()
+    d["fleet"].update(backend="pallas", devices=2)
+    again = ScenarioSpec.from_dict(d)
+    assert again.fleet.devices == 2
+    assert ScenarioSpec.from_dict(again.to_dict()) == again
+
+
+@pytest.mark.parametrize("devices", [0, -4])
+def test_fleet_spec_refuses_devices_out_of_range(devices):
+    with pytest.raises(ValueError, match="devices must be >= 1"):
+        FleetSpec(backend="pallas", devices=devices)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jit"])
+def test_fleet_spec_refuses_devices_off_the_device_backend(backend):
+    with pytest.raises(ValueError, match="needs backend='pallas'"):
+        FleetSpec(backend=backend, devices=4)
+    d = ScenarioSpec.load(f"{SCENARIOS}/fleet_scaling.json").to_dict()
+    d["fleet"].update(backend=backend, devices=2)
+    with pytest.raises(ValueError, match="needs backend='pallas'"):
+        ScenarioSpec.from_dict(d)
+
+
+def test_fleet_build_refuses_more_devices_than_jax_sees():
+    import jax
+
+    spec = ScenarioSpec.load(f"{SCENARIOS}/fleet_scaling.json")
+    seen = jax.device_count()
+    spec.fleet = FleetSpec(dt=1.0, backend="pallas", devices=seen + 1)
+    with pytest.raises(ValueError, match=f"JAX sees {seen}"):
+        spec.build("fleet")
+
+
 def test_fleet_rejects_multi_torrent():
     spec = ScenarioSpec.load(f"{SCENARIOS}/multi_torrent_fairness.json")
     with pytest.raises(ValueError):

@@ -332,7 +332,7 @@ def test_device_select_matches_engine_cand_build(stream, mode, fallback, k,
     other_p[: rows.size] = other
     rule = swarm_ops._select_rule(stream, mode, fallback)
     if chunk is None:
-        full = swarm_ops._select_jit(rule, dev.interpret)(
+        full = swarm_ops._select_jit(rule, dev.interpret, dev.mesh)(
             dev.have_rows, dev.jitter_rows, dev.repl, dev.class_rows,
             rows_p, other_p)
     else:

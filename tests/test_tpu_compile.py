@@ -16,8 +16,10 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as PS
 
 from repro.kernels.checksum import device_checksum
 from repro.kernels.swarm import kernel as swarm_kernel
@@ -25,14 +27,14 @@ from repro.kernels.swarm import ops as swarm_ops
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        topo = topologies.get_topology_desc(
+        desc = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # no TPU compiler in this install
@@ -42,9 +44,21 @@ def one_chip():
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", cache_on)
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The fleet device state's mesh over the described host's four
+    chips."""
+    return Mesh(np.array(topo.devices), (swarm_ops.PEERS,))
 
 
 def _compile(fn, shapes, sharding):
@@ -68,16 +82,25 @@ def test_rarest_argmin_compiles(one_chip, k, P, bp):
     assert "tpu_custom_call" in hlo
 
 
-def _select_lowered(n, P, k, sharding):
-    """The device select program for ``n`` clients of ``P`` pieces and a
-    ``k``-row bucket (the HTTP stream's rule with the origin rescue)."""
+def _select_lowered(n, P, k, mesh):
+    """The device select program for ``n`` clients of ``P`` pieces over
+    ``mesh`` and a ``k``-row bucket a chip (the HTTP stream's rule with
+    the origin rescue)."""
     width = swarm_ops.select_plan(P).width
-    fn = swarm_ops._select_jit("origin_or_unserved", False)
-    shapes = [((n, width, 128), jnp.uint8), ((n, width, 128), jnp.float32),
-              ((P,), jnp.int32), ((width, 128), jnp.int32),
-              ((k,), jnp.int32), ((k,), jnp.int32)]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    fn = swarm_ops._select_jit("origin_or_unserved", False, mesh)
+    by_row = NamedSharding(mesh, PS(swarm_ops.PEERS))
+    whole = NamedSharding(mesh, PS())
+    kb = k * mesh.size
+    shapes = [((n, width, 128), jnp.uint8, by_row),
+              ((n, width, 128), jnp.float32, by_row),
+              ((P,), jnp.int32, whole), ((width, 128), jnp.int32, whole),
+              ((kb,), jnp.int32, by_row), ((kb,), jnp.int32, by_row)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in shapes]
     return fn.lower(*args).compile()
+
+
+def _mesh_of(sharding):
+    return Mesh(np.array(list(sharding.device_set)), (swarm_ops.PEERS,))
 
 
 def _hlo_results(hlo):
@@ -93,7 +116,7 @@ def _hlo_results(hlo):
 def test_fleet_select_compiles_at_100k(one_chip):
     # the 100k-client crowd's largest bucket: its rows and picks go
     # through SMEM a grid step at a time, never all at once
-    hlo = _select_lowered(100_000, 125, 131072, one_chip).as_text()
+    hlo = _select_lowered(100_000, 125, 131072, _mesh_of(one_chip)).as_text()
     assert "tpu_custom_call" in hlo
 
 
@@ -104,7 +127,7 @@ def test_fleet_select_reads_rows_in_place(one_chip, k):
     # parameters, no result holds more than one value per row or one per
     # (padded) piece, and its temporaries stay small
     n, P = 16384, 37504
-    compiled = _select_lowered(n, P, k, one_chip)
+    compiled = _select_lowered(n, P, k, _mesh_of(one_chip))
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     per_piece = swarm_ops.select_plan(P).width * 128
@@ -114,6 +137,46 @@ def test_fleet_select_reads_rows_in_place(one_chip, k):
         assert "gather" not in op, (op, dims)
         assert math.prod(dims) <= max(k, per_piece), (op, dims)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _state_args(mesh, n, P, *tail):
+    """Shapes of the device state over ``mesh`` (have matrix by rows,
+    replica counts on every chip), then ``tail`` int32 vectors on every
+    chip."""
+    width = swarm_ops.select_plan(P).width
+    by_row = NamedSharding(mesh, PS(swarm_ops.PEERS))
+    whole = NamedSharding(mesh, PS())
+    return ([jax.ShapeDtypeStruct((n, width, 128), jnp.uint8,
+                                  sharding=by_row),
+             jax.ShapeDtypeStruct((P,), jnp.int32, sharding=whole)]
+            + [jax.ShapeDtypeStruct((k,), jnp.int32, sharding=whole)
+               for k in tail])
+
+
+def test_fleet_select_sharded_compiles_on_2x2(four_chips):
+    # the whole ImageNet crowd over four chips, 25,000 rows of 296 x 128
+    # pieces a chip, and the 32,768-row bucket a chip: the select reads
+    # rows in place on each chip (no gather, small temporaries) and, like
+    # the completions scatter, needs no collective; departures add the
+    # chips' sums with the path's one all-reduce
+    n, P = 100_000, 37504
+    width = swarm_ops.select_plan(P).width
+    sel = _select_lowered(n, P, 32768, four_chips)
+    hlo = sel.as_text()
+    assert "tpu_custom_call" in hlo
+    assert "gather" not in hlo and "all-reduce" not in hlo
+    mem = sel.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
+    # each chip holds its own quarter of have and jitter
+    assert mem.argument_size_in_bytes < 25_000 * width * 128 * 5 + (8 << 20)
+    add = swarm_ops._add_pieces_jit(four_chips).lower(
+        *_state_args(four_chips, n, P, 131072, 131072)).compile()
+    assert "all-reduce" not in add.as_text()
+    # over four chips the scatter writes each chip's rows in place
+    assert add.memory_analysis().alias_size_in_bytes >= 25_000 * width * 128
+    drop = swarm_ops._drop_rows_jit(four_chips).lower(
+        *_state_args(four_chips, n, P, 1024)).compile()
+    assert drop.as_text().count("all-reduce(") == 1
 
 
 def _waterfill_hlo(plan, sharding):
