@@ -71,7 +71,8 @@ operations:
   included;
 * ``fleet.waterfill`` — one rate allocation
   (``phase_seconds["waterfill"]``); on the device path its metadata
-  ``rounds`` holds the call's fixed-point rounds;
+  ``rounds`` holds the call's fixed-point rounds and ``contracted`` is 1
+  where the one-hot contraction ran it, else 0;
 * ``fleet.completions`` — the chained completion rounds, with their
   selections and resampling nested inside;
 * ``fleet.telemetry`` — the metrics sampler
@@ -1010,12 +1011,15 @@ class FleetSwarmSim:
                         if self.device is not None:
                             # both device paths handle spine links natively
                             rounds = self.device.rounds
+                            onehot = self.device.waterfill_runs["onehot"]
                             rates = self.device.waterfill(
                                 fsrc, fdst, up_cap, down_cap, link_of,
                                 link_cap,
                             )
                             span.set_metadata(
-                                rounds=self.device.rounds - rounds
+                                rounds=self.device.rounds - rounds,
+                                contracted=self.device.waterfill_runs[
+                                    "onehot"] - onehot,
                             )
                         elif self.fleet_cfg.backend == "jit" \
                                 and link_of is None:

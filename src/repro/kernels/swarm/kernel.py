@@ -26,7 +26,7 @@ the shared per-tile minimum (:func:`_lex_min`) and strictly-less merge
 ``lax.while_loop`` (all unfrozen flows grow equally until a node or
 spine-link constraint saturates; flows through it freeze; repeat — at
 least one constraint binds per round, so ``2*nodes + links + 2`` rounds
-bound the loop and the early-exit fires long before). Two paths run it:
+bound the loop and the early-exit fires long before). Three paths run it:
 
 - the Pallas kernel keeps the whole flow table in VMEM and forms the
   per-round segment sums (active flows per node/link) and per-flow
@@ -35,10 +35,14 @@ bound the loop and the early-exit fires long before). Two paths run it:
   operand is 0/1 or a small integer count with float32 accumulation. Its
   one-hot tiles grow with the node count, so it serves tables whose
   footprint (:func:`waterfill_vmem_bytes`) fits the VMEM budget;
-- :func:`waterfill_xla` runs the same rounds as plain XLA ops in device
-  memory (scatter-add segment sums, direct gathers) for larger tables.
+- :func:`waterfill_onehot` runs the same rounds as plain XLA ops in
+  device memory, its segment sums and lookups as contractions of
+  two-level one-hot incidences (``pf * pn`` MXU work a round), for
+  larger tables over up to ``ops.ONEHOT_MAX_NODES`` padded nodes;
+- :func:`waterfill_xla` runs them with per-flow scatter-adds and
+  gathers (``pf`` element-wise work a round) for tables over more nodes.
 
-Both produce bit-identical float32 results (all segment values are exact
+All produce bit-identical float32 results (all segment values are exact
 integers, gathers touch one element), pinned by the parity suite.
 
 Exactness contract: the bit-for-bit oracle is ``ref.waterfill_jnp_ref``
@@ -559,27 +563,18 @@ def waterfill_call(
     return rate.reshape(pf), rounds
 
 
-def waterfill_xla(src, dst, lnk, up_cap, down_cap, link_cap, *, n_iter: int):
-    """The kernel's fixed point as plain XLA ops: scatter-add segment
-    sums and direct gathers, over 1-D arrays in device memory, with no
-    VMEM bound. Takes the kernel's padded table (``-1`` flows start
-    frozen) or an unpadded one; returns ``((nf,) rates, rounds)``.
-    """
-    caps = (up_cap, down_cap, link_cap)
-    idx = (src, dst, lnk)
+def _fixed_point(src, caps, counts_of, hits_of, n_iter):
+    """The rounds of the two paths in device memory: ``counts_of(act)``
+    gives each constraint's active-flow count from the 0/1 float
+    ``act``, ``hits_of(sats)`` each flow's saturated constraints.
+    Returns ``((pf,) rates, rounds)``."""
 
     def body(state):
         rate, frozen, alloc, it, _ = state
         act = (~frozen).astype(jnp.float32)
-        # -1 padding wraps to the last slot, where it adds 0
-        counts = tuple(
-            jnp.zeros(c.shape[0], jnp.float32).at[i].add(act)
-            for c, i in zip(caps, idx)
-        )
-        delta, ok, alloc, sats = _fill_round(counts, alloc, caps)
+        delta, ok, alloc, sats = _fill_round(counts_of(act), alloc, caps)
         rate = rate + act * delta
-        hit = sats[0][src] + sats[1][dst] + sats[2][lnk]
-        newly = (~frozen) & (hit > 0)
+        newly = (~frozen) & (hits_of(sats) > 0)
         frozen = frozen | newly
         stop = ~(ok & newly.any()) | frozen.all()
         return rate, frozen, alloc, it + 1, stop
@@ -590,10 +585,80 @@ def waterfill_xla(src, dst, lnk, up_cap, down_cap, link_cap, *, n_iter: int):
 
     init = (
         jnp.zeros(src.shape[0], jnp.float32),
-        src < 0,
+        src < 0,  # padded flows start frozen
         tuple(jnp.zeros(c.shape[0], jnp.float32) for c in caps),
         jnp.int32(0),
         jnp.asarray(False),
     )
     rate, _, _, it, _ = lax.while_loop(cond, body, init)
     return rate, it.reshape(1)
+
+
+def waterfill_xla(src, dst, lnk, up_cap, down_cap, link_cap, *, n_iter: int):
+    """The kernel's fixed point as plain XLA ops: scatter-add segment
+    sums and direct gathers, over 1-D arrays in device memory, with no
+    VMEM bound. Takes the kernel's padded table (``-1`` flows start
+    frozen) or an unpadded one; returns ``((nf,) rates, rounds)``.
+    """
+    caps = (up_cap, down_cap, link_cap)
+
+    def counts_of(act):
+        # -1 padding wraps to the last slot, where it adds 0
+        return tuple(jnp.zeros(c.shape[0], jnp.float32).at[i].add(act)
+                     for c, i in zip(caps, (src, dst, lnk)))
+
+    def hits_of(sats):
+        return sats[0][src] + sats[1][dst] + sats[2][lnk]
+
+    return _fixed_point(src, caps, counts_of, hits_of, n_iter)
+
+
+def _incidence(idx, width):
+    """``(pf,)`` indices into a ``width``-slot channel (a multiple of
+    :data:`LANES`) -> its two-level 0/1 incidence, bfloat16: ``hi``
+    ``(pf, width // LANES)`` on ``idx // LANES`` and ``lo`` ``(pf,
+    LANES)`` on ``idx % LANES``. A ``-1`` pad matches no ``hi`` column,
+    so it adds 0 and reads 0."""
+    cols = lambda n: lax.broadcasted_iota(jnp.int32, (1, n), 1)  # noqa: E731
+    idx = idx[:, None]
+    hi = (idx // LANES == cols(width // LANES)).astype(jnp.bfloat16)
+    lo = (idx % LANES == cols(LANES)).astype(jnp.bfloat16)
+    return hi, lo
+
+
+def waterfill_onehot(src, dst, lnk, up_cap, down_cap, link_cap, *,
+                     n_iter: int):
+    """:func:`waterfill_xla`'s fixed point with each round's segment sums
+    and saturation lookups as two-level one-hot contractions on the MXU,
+    in place of per-flow scatter-adds and gathers.
+
+    A slot ``i`` of a channel is the pair ``(i // 128, i % 128)``: the
+    active flows per slot are ``hiᵀ · (act ⊙ lo)`` and a flow's
+    saturation is the row sum of ``(hi · sats) ⊙ lo``. Every operand is
+    0/1 (bfloat16 holds it exactly) and every sum an integer below 2^24
+    accumulated in float32, so counts, rounds and rates are the scatter
+    form's bit for bit. XLA builds the incidences inside the contractions'
+    fusions, so a round reads only the index vectors from HBM. Takes the
+    kernel's padded table (widths multiples of 128); returns ``((pf,)
+    rates, rounds)``.
+    """
+    caps = (up_cap, down_cap, link_cap)
+    inc = tuple(_incidence(i, c.shape[0])
+                for i, c in zip((src, dst, lnk), caps))
+
+    def counts_of(act):
+        act = act.astype(jnp.bfloat16)[:, None]
+        return tuple(
+            lax.dot_general(hi, act * lo, (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32).reshape(-1)
+            for hi, lo in inc
+        )
+
+    def hits_of(sats):
+        return sum(
+            (jnp.dot(hi, s.astype(jnp.bfloat16).reshape(-1, LANES),
+                     preferred_element_type=jnp.float32) * lo).sum(axis=1)
+            for (hi, lo), s in zip(inc, sats)
+        )
+
+    return _fixed_point(src, caps, counts_of, hits_of, n_iter)

@@ -48,6 +48,7 @@ from .kernel import (
     select_rows_call,
     select_rows_vmem_bytes,
     waterfill_call,
+    waterfill_onehot,
     waterfill_vmem_bytes,
     waterfill_xla,
 )
@@ -55,9 +56,12 @@ from .kernel import (
 BLOCK_ROWS = 128
 BLOCK_PIECES = 256
 BLOCK_FLOWS = 128
-# scoped-VMEM budget of the water-fill kernel; larger tables run the XLA
-# fixed point (v5e has 128 MiB of VMEM, 16 MiB scoped by default)
+# scoped-VMEM budget of the water-fill kernel; larger tables run in HBM
+# (v5e has 128 MiB of VMEM, 16 MiB scoped by default)
 WATERFILL_VMEM_LIMIT = 16 << 20
+# the largest padded node count the one-hot water-fill takes; above it
+# the scatter fixed point is faster (``waterfill_plan``)
+ONEHOT_MAX_NODES = 1 << 19
 # the row select's scoped-VMEM limit; rows per grid step halve until its
 # buffers take at most three quarters of it
 SELECT_VMEM_LIMIT = 16 << 20
@@ -131,19 +135,43 @@ class WaterfillPlan(NamedTuple):
     pf: int  # flows
     pn: int  # nodes
     pnl: int  # spine links + the dummy slot
-    impl: str  # "pallas" (VMEM kernel) or "xla" (fixed point in HBM)
+    # "pallas" (VMEM kernel), "onehot" (one-hot contractions in HBM) or
+    # "xla" (scatter fixed point in HBM)
+    impl: str
 
 
 def waterfill_plan(nf: int, nn: int, nl: int) -> WaterfillPlan:
     """Pad to powers of two (at least one lane width, so the few shapes a
     run sees compile once each) and pick the implementation from the
     shapes alone: the Pallas kernel when its whole table fits
-    :data:`WATERFILL_VMEM_LIMIT`, else the XLA fixed point."""
+    :data:`WATERFILL_VMEM_LIMIT`; else the one-hot contraction while the
+    padded node count is at most :data:`ONEHOT_MAX_NODES`; else the
+    scatter fixed point.
+
+    A contraction round costs ``pf * pn`` MXU work, a scatter round
+    ``pf`` element-wise scatters and gathers, so the contraction's lead
+    shrinks as ``pn`` grows. Milliseconds a round on one TPU v5e
+    (``benchmarks/waterfill_paths.py``; 16 rounds, best of 3):
+
+        pf    pn    one-hot  scatter
+        2^17  2^15     0.81     6.22
+        2^17  2^17     1.26     6.23
+        2^18  2^17     2.45    12.67
+        2^20  2^17     9.64    50.18
+        2^18  2^18     3.69    12.72
+        2^20  2^18    14.53    50.22
+        2^19  2^19    14.09    25.25
+        2^20  2^19    31.75    50.26
+        2^20  2^20    58.74    50.32
+    """
     pf, pn, pnl = (_next_pow2(x, 7) for x in (nf, nn, nl + 1))
-    fits = (
-        waterfill_vmem_bytes(pf, pn, pnl, BLOCK_FLOWS) <= WATERFILL_VMEM_LIMIT
-    )
-    return WaterfillPlan(pf, pn, pnl, "pallas" if fits else "xla")
+    if waterfill_vmem_bytes(pf, pn, pnl, BLOCK_FLOWS) <= WATERFILL_VMEM_LIMIT:
+        impl = "pallas"
+    elif pn <= ONEHOT_MAX_NODES:
+        impl = "onehot"
+    else:
+        impl = "xla"
+    return WaterfillPlan(pf, pn, pnl, impl)
 
 
 @functools.lru_cache(maxsize=None)
@@ -155,12 +183,15 @@ def _waterfill_jit(n_iter: int, impl: str, interpret: bool):
             vmem_limit_bytes=WATERFILL_VMEM_LIMIT, interpret=interpret,
         )
 
+    def fleet_waterfill_onehot(src, dst, lnk, up, dn, lcap):
+        return waterfill_onehot(src, dst, lnk, up, dn, lcap, n_iter=n_iter)
+
     def fleet_waterfill_xla(src, dst, lnk, up, dn, lcap):
         return waterfill_xla(src, dst, lnk, up, dn, lcap, n_iter=n_iter)
 
-    return jax.jit(
-        fleet_waterfill_pallas if impl == "pallas" else fleet_waterfill_xla
-    )
+    return jax.jit({"pallas": fleet_waterfill_pallas,
+                    "onehot": fleet_waterfill_onehot,
+                    "xla": fleet_waterfill_xla}[impl])
 
 
 def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
@@ -217,8 +248,9 @@ def fleet_waterfill(
     spine links supported). Bit-identical to ``ref.waterfill_jnp_ref``;
     within a band of the float64 goldens path.
 
-    ``impl=None`` takes :func:`waterfill_plan`'s choice; ``"pallas"`` or
-    ``"xla"`` forces one path (the parity tests run both).
+    ``impl=None`` takes :func:`waterfill_plan`'s choice; ``"pallas"``,
+    ``"onehot"`` or ``"xla"`` forces one path (the parity tests run
+    each).
     """
     if np.asarray(src).size == 0:
         return np.zeros(0, dtype=np.float64)
@@ -441,7 +473,7 @@ class FleetDeviceState:
         cls[:P] = swarm_class
         self.class_rows = jax.device_put(cls.reshape(width, LANES), whole)
         self.shard_rows_max = 0
-        self.waterfill_runs = {"pallas": 0, "xla": 0}
+        self.waterfill_runs = {"pallas": 0, "onehot": 0, "xla": 0}
         self.peak_flows = 0
         self.rounds = 0
 

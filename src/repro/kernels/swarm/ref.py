@@ -11,9 +11,10 @@ Three oracles, three exactness contracts:
 - :func:`waterfill_jnp_ref` — the *bit-for-bit* water-filling oracle
   (checksum-idiom pure-jnp): the XLA fixed point
   :func:`~.kernel.waterfill_xla` on the unpadded table, scatter-based.
-  Comparing both device paths against it pins exactly what they add —
-  flow tiling, the padding conventions, the dummy link slot, and the
-  kernel's one-hot segment math — with zero tolerance.
+  Comparing the device paths against it pins exactly what they add —
+  flow tiling, the padding conventions, the dummy link slot, the
+  kernel's one-hot segment math and the contraction's two-level one-hot
+  incidences — with zero tolerance.
 
 - :func:`waterfill_f32_ref` — a float32 numpy transliteration of
   :func:`repro.core.fleet.waterfill_rates` (same bincount / min ordering,
@@ -139,27 +140,33 @@ def waterfill_jnp_ref(
     down_cap: np.ndarray,
     link_of: Optional[np.ndarray] = None,
     link_cap: Optional[np.ndarray] = None,
-) -> np.ndarray:
-    """Pure-jnp water-filling oracle: :func:`~.kernel.waterfill_xla` on the
-    unpadded, untiled table.
+    *,
+    with_rounds: bool = False,
+):
+    """Pure-jnp water-filling oracle: :func:`~.kernel.waterfill_xla` (the
+    scatter fixed point) on the unpadded, untiled table.
 
-    Both device paths must match this *bit for bit* — the diff is
+    Every device path must match this *bit for bit* — the diff is
     precisely the machinery under test (the kernel's tiling and one-hot
-    segment sums; both paths' padding and dummy slots).
+    segment sums, the contraction's two-level incidences; the paths'
+    padding and dummy slots). ``with_rounds`` returns ``(rates,
+    rounds)``, the fixed point's rounds beside the rates.
     """
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     nf = src.size
     if nf == 0:
-        return np.zeros(0, dtype=F32)
+        rates, rounds = np.zeros(0, dtype=F32), 0
+        return (rates, rounds) if with_rounds else rates
     nn = np.asarray(up_cap).size
     nl, lnk, lcap = _link_channel(nf, link_of, link_cap)
-    out = _jnp_fill(2 * nn + nl + 2)(
+    rates, rounds = _jnp_fill(2 * nn + nl + 2)(
         jnp.asarray(src, dtype=jnp.int32),
         jnp.asarray(dst, dtype=jnp.int32),
         jnp.asarray(lnk, dtype=jnp.int32),
         jnp.asarray(np.asarray(up_cap, dtype=F32)),
         jnp.asarray(np.asarray(down_cap, dtype=F32)),
         jnp.asarray(lcap),
-    )[0]
-    return np.asarray(out)
+    )
+    rates = np.asarray(rates)
+    return (rates, int(np.asarray(rounds)[0])) if with_rounds else rates

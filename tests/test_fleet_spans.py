@@ -107,6 +107,15 @@ def test_waterfill_rounds_ride_on_their_spans(profiled):
     assert sum(s[2]["rounds"] for s in spans) == profiled["rounds"]
 
 
+def test_waterfill_spans_say_whether_the_contraction_ran(profiled):
+    # 24 clients' tables fit the Pallas kernel: the contraction runs none
+    spans = profiled["events"]["fleet.waterfill"]
+    runs = profiled["sim"].device.waterfill_runs
+    assert all("contracted" in s[2] for s in spans)
+    assert sum(s[2]["contracted"] for s in spans) == runs["onehot"] == 0
+    assert runs["pallas"] == len(spans)
+
+
 @pytest.mark.parametrize("name", ["fleet.resample", "fleet.flow_table",
                                   "fleet.completions", "fleet.select",
                                   "fleet.waterfill"])

@@ -3,9 +3,10 @@
 Three exactness tiers (see ``repro/kernels/swarm/ref.py``):
 
 - rarest-argmin is *index-exact* against the numpy engine hot path;
-- both device water-fill paths (the Pallas kernel and the padded XLA
-  fixed point) are *bit-exact* against the pure-jnp oracle (tiling /
-  padding / dummy-slot machinery adds nothing);
+- every device water-fill path (the Pallas kernel, the padded one-hot
+  contraction and the padded scatter fixed point) is *bit-exact* against
+  the pure-jnp scatter oracle (tiling / padding / dummy-slot / one-hot
+  machinery adds nothing);
 - against numpy references it holds a tight relative band (XLA:CPU fuses
   ``alloc + count * delta`` into FMAs; numpy rounds twice), and the
   engine-level test pins that the band never moves a piece completion on
@@ -119,7 +120,7 @@ def _random_topology(nf, nn, spine=False, inf_caps=False):
 
 @pytest.mark.parametrize("nf,nn", [(1, 2), (5, 3), (37, 10), (300, 40)])
 @pytest.mark.parametrize("spine", [False, True])
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "onehot"])
 def test_waterfill_bit_exact_vs_jnp_oracle(nf, nn, spine, impl):
     src, dst, up, dn, lof, lcap = _random_topology(nf, nn, spine=spine)
     out = fleet_waterfill(src, dst, up, dn, lof, lcap, impl=impl)
@@ -127,9 +128,27 @@ def test_waterfill_bit_exact_vs_jnp_oracle(nf, nn, spine, impl):
     np.testing.assert_array_equal(out.astype(np.float32), ref)
 
 
+@pytest.mark.parametrize("spine", [False, True])
+def test_waterfill_onehot_spans_node_blocks(spine):
+    # 3,000 nodes padded to 4,096 (32 blocks of 128) and 20,000 flows to
+    # 32,768 with -1 pads: the contraction's two-level incidences give
+    # the scatter oracle's rates and rounds bit for bit. Equal capacities
+    # keep the rounds to a few hundred
+    nf, nn = 20_000, 3_000
+    src, dst, _, _, lof, lcap = _random_topology(nf, nn, spine=spine)
+    up, dn = np.full(nn, 25.0), np.full(nn, 50.0)
+    rate, rounds, plan = swarm_ops._waterfill(
+        src, dst, up, dn, lof, lcap, "onehot", None)
+    assert (plan.pf, plan.pn) == (1 << 15, 1 << 12)
+    ref, ref_rounds = waterfill_jnp_ref(src, dst, up, dn, lof, lcap,
+                                        with_rounds=True)
+    np.testing.assert_array_equal(rate.astype(np.float32), ref)
+    assert rounds == ref_rounds > 1
+
+
 def test_waterfill_bit_exact_with_inf_caps():
     src, dst, up, dn, lof, lcap = _random_topology(80, 12, inf_caps=True)
-    for impl in ("xla", "pallas"):
+    for impl in ("xla", "pallas", "onehot"):
         out = fleet_waterfill(src, dst, up, dn, impl=impl)
         np.testing.assert_array_equal(
             out.astype(np.float32), waterfill_jnp_ref(src, dst, up, dn)
@@ -152,16 +171,29 @@ def test_waterfill_band_vs_numpy_refs():
 
 
 def test_waterfill_empty_and_zero_cap():
-    assert fleet_waterfill(
-        np.zeros(0, np.int64), np.zeros(0, np.int64),
-        np.ones(2), np.ones(2),
-    ).size == 0
-    # zero-capacity uplink: all its flows freeze at 0 immediately
-    out = fleet_waterfill(
-        np.zeros(4, np.int64), np.arange(1, 5),
-        np.array([0.0, 10, 10, 10, 10]), np.full(5, 10.0),
-    )
-    np.testing.assert_array_equal(out, np.zeros(4))
+    for impl in (None, "onehot"):
+        assert fleet_waterfill(
+            np.zeros(0, np.int64), np.zeros(0, np.int64),
+            np.ones(2), np.ones(2), impl=impl,
+        ).size == 0
+        # zero-capacity uplink: all its flows freeze at 0 immediately
+        out = fleet_waterfill(
+            np.zeros(4, np.int64), np.arange(1, 5),
+            np.array([0.0, 10, 10, 10, 10]), np.full(5, 10.0), impl=impl,
+        )
+        np.testing.assert_array_equal(out, np.zeros(4))
+
+
+@pytest.mark.parametrize("nf,nn,want", [
+    (14_000, 2_001, "pallas"),     # the 2k crowd: the table fits VMEM
+    (90_000, 16_385, "onehot"),    # the one-chip ImageNet cell's largest
+    (200_000, 100_001, "onehot"),  # the 100k crowd's (pn 2^17)
+    (1_000_000, 524_288, "onehot"),  # the last node count it takes
+    (1_000_000, 524_289, "xla"),     # the first it leaves to the scatter
+    (2_000_000, 1_000_001, "xla"),   # a 1M-client crowd (pn 2^20)
+])
+def test_waterfill_plan_picks_the_path_from_padded_shapes(nf, nn, want):
+    assert swarm_ops.waterfill_plan(nf, nn, 0).impl == want
 
 
 def _count_compiles(fn):

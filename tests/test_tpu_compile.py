@@ -179,18 +179,44 @@ def test_fleet_select_sharded_compiles_on_2x2(four_chips):
     assert drop.as_text().count("all-reduce(") == 1
 
 
-def _waterfill_hlo(plan, sharding):
+def _waterfill_compiled(plan, sharding):
     fn = swarm_ops._waterfill_jit(2 * plan.pn, plan.impl, False)
     flows = [((plan.pf,), jnp.int32)] * 3
     caps = [((plan.pn,), jnp.float32)] * 2 + [((plan.pnl,), jnp.float32)]
-    return _compile(fn, flows + caps, sharding)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in flows + caps]
+    return fn.lower(*args).compile()
+
+
+def _waterfill_hlo(plan, sharding):
+    return _waterfill_compiled(plan, sharding).as_text()
 
 
 def test_waterfill_at_100k_runs_the_xla_fixed_point(one_chip):
-    # ~700k flows over 100,001 nodes: the kernel's table cannot fit VMEM
+    # ~700k flows over 100,001 nodes: the kernel's table cannot fit VMEM,
+    # and pn 2^17 is within the one-hot contraction's reach
     plan = swarm_ops.waterfill_plan(705_210, 100_001, 0)
-    assert (plan.pf, plan.pn, plan.impl) == (1 << 20, 1 << 17, "xla")
-    assert "tpu_custom_call" not in _waterfill_hlo(plan, one_chip)
+    assert (plan.pf, plan.pn, plan.impl) == (1 << 20, 1 << 17, "onehot")
+    hlo = _waterfill_hlo(plan, one_chip)
+    assert "tpu_custom_call" not in hlo
+    assert "scatter" not in {op for op, _ in _hlo_results(hlo)}
+
+
+@pytest.mark.parametrize("pf,pn", [
+    (1 << 17, 1 << 15),  # the one-chip ImageNet cell's largest table
+    (1 << 18, 1 << 17),  # the 100k crowd's, on the four-chip cell's chip 0
+])
+def test_waterfill_onehot_contracts_without_scatters(one_chip, pf, pn):
+    # every round's counts and lookups are contractions (convolutions)
+    # whose one-hot operands are built inside their fusions: no scatter
+    # or gather is left, and no (pf, pn / 128) incidence, 64 MiB and up
+    # here, is kept in HBM
+    plan = swarm_ops.WaterfillPlan(pf, pn, 128, "onehot")
+    compiled = _waterfill_compiled(plan, one_chip)
+    ops = {op for op, _ in _hlo_results(compiled.as_text())}
+    assert "convolution" in ops
+    assert "scatter" not in ops and "gather" not in ops
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 def test_waterfill_kernel_compiles_where_the_plan_picks_it(one_chip):
