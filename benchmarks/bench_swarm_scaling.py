@@ -52,7 +52,7 @@ def fleet_point(spec: ScenarioSpec, n: int, backend=None, dt=None):
     """One fleet-engine flash crowd of ``n`` clients from the base spec."""
     fleet = spec.fleet
     if backend is not None:
-        fleet = dataclasses.replace(fleet, jit=False, backend=backend)
+        fleet = dataclasses.replace(fleet, backend=backend)
     if dt is not None:
         fleet = dataclasses.replace(fleet, dt=dt)
     point = dataclasses.replace(

@@ -2,12 +2,12 @@
 shapes, on the chip, and check them against each other bit for bit.
 
 ``waterfill_plan`` picks the one-hot contraction (``"onehot"``) or the
-scatter fixed point (``"xla"``) for a table too large for the Pallas
-kernel from its padded node count alone: the contraction's cost per
-round grows with ``pf * pn``, the scatter's with ``pf``. This script
-measures where they cross. Each call runs exactly ``ROUNDS`` rounds
-(capacities drawn uniformly, so that no round ends the fixed point
-early) over a table already on the device; the time of the best of
+scatter fixed point (``"xla"``) from a table's padded node count alone:
+the contraction's cost per round grows with ``pf * pn``, the scatter's
+with ``pf``. This script measures where they cross. Each call runs
+exactly ``ROUNDS`` rounds (capacities drawn uniformly, so that no round
+ends the fixed point early) over a table already on the device; the
+time of the best of
 ``REPEATS`` calls, divided by ``ROUNDS``, is the round's time. Rates and
 rounds of both paths are compared bit for bit at every shape, and once
 at a fleet-like table that runs to its own end.
@@ -96,7 +96,7 @@ def main() -> None:
         row = {"pf": pf, "pn": pn}
         out = {}
         for impl in ("onehot", "xla"):
-            fn = ops._waterfill_jit(ROUNDS, impl, False)
+            fn = ops._waterfill_jit(ROUNDS, impl)
             row[f"{impl}_ms_per_round"] = 1e3 * timed(fn, tab) / ROUNDS
             out[impl] = run(fn, tab)
         row["rounds"] = out["xla"][1]
@@ -108,7 +108,7 @@ def main() -> None:
     # a fleet-like table run to its own end: the same rounds and rates
     pf, pn = 1 << 17, 1 << 15
     tab = table(pf, pn, rng, classes=True)
-    ends = {impl: run(ops._waterfill_jit(2 * pn + 2, impl, False), tab)
+    ends = {impl: run(ops._waterfill_jit(2 * pn + 2, impl), tab)
             for impl in ("onehot", "xla")}
     natural = {"pf": pf, "pn": pn, "rounds": ends["xla"][1],
                "bit_equal": bool(ends["onehot"][1] == ends["xla"][1]

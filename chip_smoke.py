@@ -65,10 +65,7 @@ def fleet_crowd(n: int = N_PEERS) -> dict:
     """Run the flash crowd of ``n`` clients on ``backend="pallas"`` and
     check it against its pinned row; raises on any miss."""
     from repro.core.scenario import ScenarioSpec
-    from repro.kernels.swarm.kernel import waterfill_vmem_bytes
-    from repro.kernels.swarm.ops import (
-        BLOCK_FLOWS, WATERFILL_VMEM_LIMIT, waterfill_plan,
-    )
+    from repro.kernels.swarm.ops import waterfill_plan
 
     spec = json.loads(SCENARIO.read_text())
     spec["arrivals"][0]["n"] = n
@@ -116,9 +113,7 @@ def fleet_crowd(n: int = N_PEERS) -> dict:
     ), flush=True)
     # the water-fill path is chosen from padded shapes alone
     plan = waterfill_plan(dev.peak_flows, n + len(spec["fabric"]["mirrors"]), 0)
-    vmem = waterfill_vmem_bytes(plan.pf, plan.pn, plan.pnl, BLOCK_FLOWS)
     print(f"waterfill at peak flows: pf={plan.pf} pn={plan.pn} "
-          f"kernel_vmem_bytes={vmem} budget={WATERFILL_VMEM_LIMIT} "
           f"-> {plan.impl}", flush=True)
     check(res.completed == n, f"{res.completed}/{n} clients done")
     check(abs(res.ticks - ref["ticks"]) <= max(5, 0.02 * ref["ticks"]),

@@ -18,13 +18,12 @@ to the *whole* hot path:
   structure (and float semantics) of ``_recompute_rates``, so the two are
   equivalence-tested against each other on random topologies;
 * one tick is one synchronous vectorized step of ``dt`` seconds — numpy
-  first, with device offload behind ``FleetSpec.backend``: ``"jit"``
-  routes water-filling through a ``jax.jit`` float32 kernel, ``"pallas"``
-  makes the tick device-resident — the have matrix, replica counts, and
-  tie-break jitter stay on the accelerator across ticks, selection runs as
-  a Pallas kernel and water-filling on the device as well
-  (:mod:`repro.kernels.swarm`).
-  Float32 backends are a throughput choice, never used for goldens.
+  by default, with device offload behind ``FleetSpec.backend="pallas"``,
+  which makes the tick device-resident — the have matrix, replica counts,
+  and tie-break jitter stay on the accelerator across ticks, selection
+  runs as a Pallas kernel and water-filling as a float32 fixed point on
+  the device as well (:mod:`repro.kernels.swarm`).
+  The float32 backend is a throughput choice, never used for goldens.
 
 Fidelity model (the documented small-N equivalence bound)
 ---------------------------------------------------------
@@ -91,7 +90,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from time import perf_counter
 from typing import Optional, Sequence
 
@@ -201,78 +199,6 @@ def waterfill_rates(
     return rate
 
 
-_JAX_FILL_CACHE: dict = {}
-
-
-def _jax_waterfill(src, dst, up_cap, down_cap):
-    """``jax.jit`` water-filling (float32, link-free).
-
-    Pads flows/nodes to powers of two so re-ticking never re-traces: dummy
-    flows target a zero-capacity dummy node, so the first filling round
-    freezes them at rate 0 and every later round matches the numpy loop.
-    Used only behind ``FleetSpec.jit`` — float32 on accelerator backends is
-    a throughput choice, never a goldens path.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    nf, nn = src.size, up_cap.size
-    pf = 1 << max(3, (nf - 1).bit_length())
-    pn = 1 << max(2, (nn).bit_length())  # >= nn + 1 dummy node
-    key = (pf, pn)
-    if key not in _JAX_FILL_CACHE:
-        n_iter = 2 * pn + 2
-
-        def fill(s, d, up, dn):
-            def body(state):
-                rate, frozen, up_a, dn_a, it, done = state
-                act = (~frozen).astype(jnp.float32)
-                n_up = jnp.zeros(pn, jnp.float32).at[s].add(act)
-                n_dn = jnp.zeros(pn, jnp.float32).at[d].add(act)
-                du = jnp.where(n_up > 0, (up - up_a) / n_up, jnp.inf)
-                dd = jnp.where(n_dn > 0, (dn - dn_a) / n_dn, jnp.inf)
-                delta = jnp.minimum(du.min(), dd.min())
-                ok = jnp.isfinite(delta)
-                delta = jnp.where(ok, jnp.maximum(delta, 0.0), 0.0)
-                rate = rate + act * delta
-                up_a = up_a + n_up * delta
-                dn_a = dn_a + n_dn * delta
-                sat_u = (du <= delta + 1e-6) & (n_up > 0)
-                sat_d = (dd <= delta + 1e-6) & (n_dn > 0)
-                newly = (~frozen) & (sat_u[s] | sat_d[d])
-                done = ~(ok & newly.any())
-                return (rate, frozen | newly, up_a, dn_a, it + 1, done)
-
-            def cond(state):
-                _, frozen, _, _, it, done = state
-                return (~done) & (it < n_iter) & (~frozen.all())
-
-            init = (
-                jnp.zeros(pf, jnp.float32),
-                jnp.zeros(pf, dtype=bool),
-                jnp.zeros(pn, jnp.float32),
-                jnp.zeros(pn, jnp.float32),
-                0,
-                False,
-            )
-            return lax.while_loop(cond, body, init)[0]
-
-        _JAX_FILL_CACHE[key] = jax.jit(fill)
-
-    dummy = pn - 1  # zero-cap sink: padded flows freeze at 0 immediately
-    s = np.full(pf, dummy, dtype=np.int32)
-    d = np.full(pf, dummy, dtype=np.int32)
-    s[:nf] = src
-    d[:nf] = dst
-    up = np.zeros(pn, dtype=np.float32)
-    dn = np.zeros(pn, dtype=np.float32)
-    up[:nn] = np.minimum(up_cap, np.float32(np.finfo(np.float32).max))
-    dn[:nn] = np.minimum(down_cap, np.float32(np.finfo(np.float32).max))
-    out = _JAX_FILL_CACHE[(pf, pn)](s, d, up, dn)
-    return np.asarray(out[:nf], dtype=np.float64)
-
-
 # --------------------------------------------------------------------------- spec
 
 
@@ -288,9 +214,8 @@ class FleetSpec:
 
     ``backend`` selects the tick's compute path:
 
-    - ``"numpy"`` — the float64 reference semantics (the goldens path);
-    - ``"jit"`` — water-filling through the ``jax.jit`` float32 kernel
-      (spine-linked topologies still fall back to numpy);
+    - ``"numpy"`` (the default) — the float64 reference semantics (the
+      goldens path);
     - ``"pallas"`` — device-resident tick: Pallas selection kernel and
       device water-fill (``repro.kernels.swarm``), have-matrix / replica counts /
       jitter held on device across ticks. Raises where the installed jax
@@ -304,15 +229,14 @@ class FleetSpec:
     than 1 needs ``backend="pallas"``; ``ScenarioSpec.build("fleet")``
     refuses more than JAX sees.
 
-    ``None`` normalizes from the deprecated ``jit`` flag (``True`` ->
-    ``"jit"``, else ``"numpy"``); after ``__post_init__`` the two fields
-    are always consistent (``jit == (backend == "jit")``).
+    One rule reads files written before the ``"jit"`` backend was
+    retired: :meth:`from_dict` drops their ``"jit": false`` key and
+    refuses ``"jit": true``.
     """
 
     dt: Optional[float] = None
     fanout: Optional[int] = None
-    jit: bool = False
-    backend: Optional[str] = None
+    backend: str = "numpy"
     devices: int = 1
 
     def __post_init__(self) -> None:
@@ -323,23 +247,11 @@ class FleetSpec:
         if self.devices < 1:
             raise ValueError(
                 f"fleet devices must be >= 1 (got {self.devices})")
-        if self.backend is None:
-            if self.jit:
-                warnings.warn(
-                    "FleetSpec.jit is deprecated; use backend='jit'",
-                    DeprecationWarning,
-                    stacklevel=2,
-                )
-            self.backend = "jit" if self.jit else "numpy"
-        elif self.backend not in ("numpy", "jit", "pallas"):
+        if self.backend not in ("numpy", "pallas"):
             raise ValueError(
-                f"fleet backend must be numpy|jit|pallas (got {self.backend!r})"
+                "fleet backend must be 'numpy' or 'pallas' "
+                f"(got {self.backend!r})"
             )
-        elif self.jit and self.backend != "jit":
-            raise ValueError(
-                f"deprecated jit=True conflicts with backend={self.backend!r}"
-            )
-        self.jit = self.backend == "jit"
         if self.devices > 1 and self.backend != "pallas":
             raise ValueError(
                 f"fleet devices={self.devices} needs backend='pallas' (the "
@@ -351,6 +263,13 @@ class FleetSpec:
 
     @classmethod
     def from_dict(cls, data: dict) -> "FleetSpec":
+        if isinstance(data, dict) and "jit" in data:
+            data = dict(data)
+            if data.pop("jit"):
+                raise ValueError(
+                    "fleet jit=true selected the retired 'jit' backend; "
+                    "use backend 'numpy' or 'pallas'"
+                )
         return spec_from_dict(cls, data)
 
 
@@ -1020,11 +939,6 @@ class FleetSwarmSim:
                                 rounds=self.device.rounds - rounds,
                                 contracted=self.device.waterfill_runs[
                                     "onehot"] - onehot,
-                            )
-                        elif self.fleet_cfg.backend == "jit" \
-                                and link_of is None:
-                            rates = _jax_waterfill(
-                                fsrc, fdst, up_cap, down_cap
                             )
                         else:
                             rates = waterfill_rates(

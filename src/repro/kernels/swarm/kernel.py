@@ -26,30 +26,24 @@ the shared per-tile minimum (:func:`_lex_min`) and strictly-less merge
 ``lax.while_loop`` (all unfrozen flows grow equally until a node or
 spine-link constraint saturates; flows through it freeze; repeat — at
 least one constraint binds per round, so ``2*nodes + links + 2`` rounds
-bound the loop and the early-exit fires long before). Three paths run it:
+bound the loop and the early-exit fires long before). Two paths run it,
+both plain XLA ops in device memory:
 
-- the Pallas kernel keeps the whole flow table in VMEM and forms the
-  per-round segment sums (active flows per node/link) and per-flow
-  saturation gathers in flow tiles of ``block`` as one-hot matmuls —
-  MXU-shaped, and exact even under bfloat16 MXU inputs because every
-  operand is 0/1 or a small integer count with float32 accumulation. Its
-  one-hot tiles grow with the node count, so it serves tables whose
-  footprint (:func:`waterfill_vmem_bytes`) fits the VMEM budget;
-- :func:`waterfill_onehot` runs the same rounds as plain XLA ops in
-  device memory, its segment sums and lookups as contractions of
-  two-level one-hot incidences (``pf * pn`` MXU work a round), for
-  larger tables over up to ``ops.ONEHOT_MAX_NODES`` padded nodes;
+- :func:`waterfill_onehot` forms each round's segment sums (active flows
+  per node/link) and per-flow saturation lookups as contractions of
+  two-level one-hot incidences on the MXU (``pf * pn`` work a round),
+  for tables over up to ``ops.ONEHOT_MAX_NODES`` padded nodes;
 - :func:`waterfill_xla` runs them with per-flow scatter-adds and
   gathers (``pf`` element-wise work a round) for tables over more nodes.
 
-All produce bit-identical float32 results (all segment values are exact
+Both produce bit-identical float32 results (all segment values are exact
 integers, gathers touch one element), pinned by the parity suite.
 
 Exactness contract: the bit-for-bit oracle is ``ref.waterfill_jnp_ref``
 (:func:`waterfill_xla` on the unpadded table), which pins everything the
-device paths add — tiling, padding, the dummy link slot, one-hot segment
-math. The numpy transliteration ``ref.waterfill_f32_ref``
-is ulp-close but *not* bitwise: XLA:CPU unconditionally contracts the
+device paths add — padding, the dummy link slot, one-hot segment math.
+The numpy transliteration ``ref.waterfill_f32_ref`` is ulp-close but
+*not* bitwise: XLA:CPU unconditionally contracts the
 ``alloc + count * delta`` multiply-adds into single-rounded FMAs
 (``lax.optimization_barrier`` does not reach LLVM's codegen), while numpy
 rounds the multiply and add separately.
@@ -58,8 +52,8 @@ Padding conventions (``ops.py`` supplies them): argmin pads rows/pieces
 with ``cand=False``; water-filling pads flows with ``src = dst = -1``
 (pre-frozen at rate 0, matching one-hot rows of zeros), nodes with zero
 capacity and zero degree, and maps unlinked flows to a dummy link slot of
-infinite capacity so the link channel always exists and the kernel takes
-the same branches with and without a spine.
+infinite capacity so the link channel always exists and the fixed point
+takes the same branches with and without a spine.
 """
 
 from __future__ import annotations
@@ -395,8 +389,9 @@ def select_rows_call(
 
 
 def _fill_round(counts, alloc, caps):
-    """One progressive-filling round at the constraints, shared by the
-    kernel and :func:`waterfill_xla` so both run the same float ops.
+    """One progressive-filling round at the constraints, which
+    :func:`_fixed_point` runs for both paths, so both run the same float
+    ops.
 
     ``counts`` / ``alloc`` / ``caps`` are ``(up, down, link)`` triples of
     active-flow counts, allocated rate and capacity per constraint. Returns
@@ -419,148 +414,6 @@ def _fill_round(counts, alloc, caps):
         for d, n in zip(ds, counts)
     )
     return delta, ok, alloc, sats
-
-
-def _waterfill_kernel(
-    src_ref, dst_ref, lnk_ref, up_ref, dn_ref, lcap_ref,
-    rate_ref, rounds_ref, frozen_ref, *, n_iter: int
-):
-    nt, block = src_ref.shape
-    idx_refs = (src_ref, dst_ref, lnk_ref)
-    caps = (up_ref[...], dn_ref[...], lcap_ref[...])  # (1, width) rows
-    widths = tuple(c.shape[1] for c in caps)
-
-    def tile(ref, t):
-        return ref[pl.ds(t, 1), :]  # one (1, block) row of flows
-
-    def onehot(idx, width):
-        # (1, block) indices -> (width, block) 0/1, flows along lanes;
-        # -1 padding matches no row
-        rows = lax.broadcasted_iota(jnp.int32, (width, block), 0)
-        return (rows == idx).astype(jnp.float32)
-
-    def seg_sum(w, idx, width):  # (1, block) weights -> (1, width) sums
-        return lax.dot_general(
-            w, onehot(idx, width), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    def gather(v, idx, width):  # (1, width) values -> (1, block)
-        return jnp.dot(
-            v, onehot(idx, width), preferred_element_type=jnp.float32
-        )
-
-    rate_ref[...] = jnp.zeros_like(rate_ref)
-    # padded flows (src = -1) start frozen at rate 0
-    frozen_ref[...] = (src_ref[...] < 0).astype(jnp.float32)
-
-    def body(state):
-        it, _, alloc = state
-
-        def count(t, acc):
-            act = 1.0 - tile(frozen_ref, t)
-            return tuple(
-                a + seg_sum(act, tile(r, t), w)
-                for a, r, w in zip(acc, idx_refs, widths)
-            )
-
-        zeros = tuple(jnp.zeros((1, w), jnp.float32) for w in widths)
-        counts = lax.fori_loop(0, nt, count, zeros)
-        delta, ok, alloc, sats = _fill_round(counts, alloc, caps)
-
-        def advance(t, carry):
-            any_new, any_live = carry
-            fr = tile(frozen_ref, t)
-            act = 1.0 - fr
-            rate_ref[pl.ds(t, 1), :] = tile(rate_ref, t) + act * delta
-            hit = sum(
-                gather(s, tile(r, t), w)
-                for s, r, w in zip(sats, idx_refs, widths)
-            )
-            newly = jnp.where(hit > 0, act, 0.0)
-            fr = fr + newly
-            frozen_ref[pl.ds(t, 1), :] = fr
-            return (
-                jnp.maximum(any_new, newly.max()),
-                jnp.maximum(any_live, (1.0 - fr).max()),
-            )
-
-        any_new, any_live = lax.fori_loop(
-            0, nt, advance, (jnp.float32(0.0), jnp.float32(0.0))
-        )
-        stop = jnp.logical_not(ok & (any_new > 0)) | (any_live == 0)
-        return it + 1, stop.astype(jnp.int32), alloc
-
-    def cond(state):
-        it, stop, _ = state
-        return (stop == 0) & (it < n_iter)
-
-    alloc0 = tuple(jnp.zeros((1, w), jnp.float32) for w in widths)
-    it, _, _ = lax.while_loop(cond, body, (jnp.int32(0), jnp.int32(0), alloc0))
-    rounds_ref[0] = it
-
-
-def waterfill_vmem_bytes(pf: int, pn: int, pnl: int, block: int) -> int:
-    """Upper bound on the water-fill kernel's VMEM footprint.
-
-    The kernel holds the whole flow table in VMEM: three index rows, the
-    rates and the frozen mask (each ``pf`` 32-bit words, inputs and
-    outputs double-buffered), the node and link rows (padded to 8
-    sublanes), and per flow tile the one-hot ``(width, block)`` float32
-    operands with their int32 iotas. Computed from shapes alone, so
-    ``fleet_waterfill`` can choose its path before anything compiles.
-    """
-    flows = (2 * 4 + 1) * 4 * pf
-    rows = 16 * 8 * 4 * (2 * pn + pnl)
-    tiles = 3 * 2 * 4 * block * (2 * pn + pnl)
-    return flows + rows + tiles
-
-
-def waterfill_call(
-    src: jax.Array,
-    dst: jax.Array,
-    lnk: jax.Array,
-    up_cap: jax.Array,
-    down_cap: jax.Array,
-    link_cap: jax.Array,
-    *,
-    n_iter: int,
-    block: int,
-    vmem_limit_bytes: int,
-    interpret: bool,
-):
-    """Padded flow table -> ``((pf,) float32 rates, (1,) int32 rounds)``.
-
-    ``src``/``dst``/``lnk`` are int32 node/link indices per flow (``-1``
-    src/dst = padding; ``lnk`` already maps unlinked flows to the dummy
-    slot). The fixed point is sequential, so the kernel is single-program
-    (no pallas grid): the whole table sits in VMEM as ``(pf // block,
-    block)`` rows, and each round walks the rows, forming per-node and
-    per-link segment sums and saturation gathers as one-hot matmuls.
-    """
-    pf = src.shape[0]
-    assert pf % block == 0
-    nt = pf // block
-    flows = lambda x: x.reshape(nt, block)  # noqa: E731
-    row = lambda x: x.reshape(1, x.shape[0])  # noqa: E731
-    rate, rounds = pl.pallas_call(
-        functools.partial(_waterfill_kernel, n_iter=n_iter),
-        out_shape=(
-            jax.ShapeDtypeStruct((nt, block), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ),
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ),
-        scratch_shapes=[pltpu.VMEM((nt, block), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_limit_bytes
-        ),
-        interpret=interpret,
-    )(flows(src), flows(dst), flows(lnk),
-      row(up_cap), row(down_cap), row(link_cap))
-    return rate.reshape(pf), rounds
 
 
 def _fixed_point(src, caps, counts_of, hits_of, n_iter):
@@ -595,10 +448,10 @@ def _fixed_point(src, caps, counts_of, hits_of, n_iter):
 
 
 def waterfill_xla(src, dst, lnk, up_cap, down_cap, link_cap, *, n_iter: int):
-    """The kernel's fixed point as plain XLA ops: scatter-add segment
-    sums and direct gathers, over 1-D arrays in device memory, with no
-    VMEM bound. Takes the kernel's padded table (``-1`` flows start
-    frozen) or an unpadded one; returns ``((nf,) rates, rounds)``.
+    """The fixed point with scatter-add segment sums and direct
+    gathers, over 1-D arrays in device memory. Takes the padded table of
+    ``ops.py`` (``-1`` flows start frozen) or an unpadded one; returns
+    ``((nf,) rates, rounds)``.
     """
     caps = (up_cap, down_cap, link_cap)
 
@@ -639,8 +492,8 @@ def waterfill_onehot(src, dst, lnk, up_cap, down_cap, link_cap, *,
     accumulated in float32, so counts, rounds and rates are the scatter
     form's bit for bit. XLA builds the incidences inside the contractions'
     fusions, so a round reads only the index vectors from HBM. Takes the
-    kernel's padded table (widths multiples of 128); returns ``((pf,)
-    rates, rounds)``.
+    padded table of ``ops.py`` (widths multiples of 128); returns
+    ``((pf,) rates, rounds)``.
     """
     caps = (up_cap, down_cap, link_cap)
     inc = tuple(_incidence(i, c.shape[0])

@@ -4,9 +4,11 @@ Three layers, mirroring the checksum/attention packages:
 
 - :func:`rarest_argmin` / :func:`fleet_waterfill` — numpy-in/numpy-out
   convenience wrappers that pad to kernel tile multiples (rows/pieces with
-  ``cand=False``; flows to a power of two with ``src = dst = -1``
+  ``cand=False``) or to powers of two (flows with ``src = dst = -1``
   pre-frozen padding; unlinked flows onto the infinite-capacity dummy link
   slot) and cache one ``jax.jit`` entry point per static configuration.
+  The water-fill runs one of two float32 device paths, chosen from the
+  padded node count (:func:`waterfill_plan`).
 
 - :class:`FleetDeviceState` — what ``FleetSpec.backend = "pallas"`` hangs
   onto: the have matrix, the fixed float32 jitter, and the replica counts
@@ -23,9 +25,10 @@ Three layers, mirroring the checksum/attention packages:
   every chip of a 1-D mesh of ``devices`` chips (one chip included), each
   on its own block of client rows.
 
-``interpret=None`` resolves per platform
-(:func:`repro.accel.pallas_interpret`): compiled on a TPU, the Pallas
-interpreter on the CPU test backend.
+``interpret=None`` resolves the Pallas calls (the select kernels) per
+platform (:func:`repro.accel.pallas_interpret`): compiled on a TPU, the
+Pallas interpreter on the CPU test backend. The water-fill is plain XLA
+ops and needs no such flag.
 """
 
 from __future__ import annotations
@@ -47,18 +50,12 @@ from .kernel import (
     rarest_argmin_call,
     select_rows_call,
     select_rows_vmem_bytes,
-    waterfill_call,
     waterfill_onehot,
-    waterfill_vmem_bytes,
     waterfill_xla,
 )
 
 BLOCK_ROWS = 128
 BLOCK_PIECES = 256
-BLOCK_FLOWS = 128
-# scoped-VMEM budget of the water-fill kernel; larger tables run in HBM
-# (v5e has 128 MiB of VMEM, 16 MiB scoped by default)
-WATERFILL_VMEM_LIMIT = 16 << 20
 # the largest padded node count the one-hot water-fill takes; above it
 # the scatter fixed point is faster (``waterfill_plan``)
 ONEHOT_MAX_NODES = 1 << 19
@@ -135,18 +132,15 @@ class WaterfillPlan(NamedTuple):
     pf: int  # flows
     pn: int  # nodes
     pnl: int  # spine links + the dummy slot
-    # "pallas" (VMEM kernel), "onehot" (one-hot contractions in HBM) or
-    # "xla" (scatter fixed point in HBM)
+    # "onehot" (one-hot contractions) or "xla" (scatter fixed point)
     impl: str
 
 
 def waterfill_plan(nf: int, nn: int, nl: int) -> WaterfillPlan:
     """Pad to powers of two (at least one lane width, so the few shapes a
     run sees compile once each) and pick the implementation from the
-    shapes alone: the Pallas kernel when its whole table fits
-    :data:`WATERFILL_VMEM_LIMIT`; else the one-hot contraction while the
-    padded node count is at most :data:`ONEHOT_MAX_NODES`; else the
-    scatter fixed point.
+    padded node count alone: the one-hot contraction while it is at most
+    :data:`ONEHOT_MAX_NODES`, else the scatter fixed point.
 
     A contraction round costs ``pf * pn`` MXU work, a scatter round
     ``pf`` element-wise scatters and gathers, so the contraction's lead
@@ -165,38 +159,27 @@ def waterfill_plan(nf: int, nn: int, nl: int) -> WaterfillPlan:
         2^20  2^20    58.74    50.32
     """
     pf, pn, pnl = (_next_pow2(x, 7) for x in (nf, nn, nl + 1))
-    if waterfill_vmem_bytes(pf, pn, pnl, BLOCK_FLOWS) <= WATERFILL_VMEM_LIMIT:
-        impl = "pallas"
-    elif pn <= ONEHOT_MAX_NODES:
-        impl = "onehot"
-    else:
-        impl = "xla"
+    impl = "onehot" if pn <= ONEHOT_MAX_NODES else "xla"
     return WaterfillPlan(pf, pn, pnl, impl)
 
 
 @functools.lru_cache(maxsize=None)
-def _waterfill_jit(n_iter: int, impl: str, interpret: bool):
+def _waterfill_jit(n_iter: int, impl: str):
     # named functions, not partials: a profile shows each program by name
-    def fleet_waterfill_pallas(src, dst, lnk, up, dn, lcap):
-        return waterfill_call(
-            src, dst, lnk, up, dn, lcap, n_iter=n_iter, block=BLOCK_FLOWS,
-            vmem_limit_bytes=WATERFILL_VMEM_LIMIT, interpret=interpret,
-        )
-
     def fleet_waterfill_onehot(src, dst, lnk, up, dn, lcap):
         return waterfill_onehot(src, dst, lnk, up, dn, lcap, n_iter=n_iter)
 
     def fleet_waterfill_xla(src, dst, lnk, up, dn, lcap):
         return waterfill_xla(src, dst, lnk, up, dn, lcap, n_iter=n_iter)
 
-    return jax.jit({"pallas": fleet_waterfill_pallas,
-                    "onehot": fleet_waterfill_onehot,
+    return jax.jit({"onehot": fleet_waterfill_onehot,
                     "xla": fleet_waterfill_xla}[impl])
 
 
-def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
-               interpret):
-    """Pad, run on the device, unpad: ``(rates, rounds, plan)``."""
+def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl=None):
+    """Pad, run on the device, unpad: ``(rates, rounds, plan)``.
+    ``impl=None`` takes :func:`waterfill_plan`'s choice; ``"onehot"`` or
+    ``"xla"`` forces one path (the parity tests run each)."""
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
     nf = src.size
@@ -226,8 +209,7 @@ def _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
     lc[nl] = np.inf
     if nl:
         lc[:nl] = link_cap
-    rate, rounds = _waterfill_jit(n_iter, plan.impl, _resolve_interpret(
-        interpret))(s, d, lk, up, dn, lc)
+    rate, rounds = _waterfill_jit(n_iter, plan.impl)(s, d, lk, up, dn, lc)
     # unpad on the host: a device-side slice would compile once per nf
     rate = np.asarray(rate, dtype=np.float64)[:nf]
     return rate, int(np.asarray(rounds)[0]), plan
@@ -240,22 +222,15 @@ def fleet_waterfill(
     down_cap: np.ndarray,
     link_of: Optional[np.ndarray] = None,
     link_cap: Optional[np.ndarray] = None,
-    *,
-    impl: Optional[str] = None,
-    interpret=None,
 ) -> np.ndarray:
     """Device-backed :func:`~repro.core.fleet.waterfill_rates` (float32;
-    spine links supported). Bit-identical to ``ref.waterfill_jnp_ref``;
+    spine links supported), on the path :func:`waterfill_plan` picks from
+    the padded node count. Bit-identical to ``ref.waterfill_jnp_ref``;
     within a band of the float64 goldens path.
-
-    ``impl=None`` takes :func:`waterfill_plan`'s choice; ``"pallas"``,
-    ``"onehot"`` or ``"xla"`` forces one path (the parity tests run
-    each).
     """
     if np.asarray(src).size == 0:
         return np.zeros(0, dtype=np.float64)
-    return _waterfill(src, dst, up_cap, down_cap, link_of, link_cap, impl,
-                      interpret)[0]
+    return _waterfill(src, dst, up_cap, down_cap, link_of, link_cap)[0]
 
 
 # --------------------------------------------------------------------------- device state
@@ -442,9 +417,10 @@ class FleetDeviceState:
     call's count); completions go to every chip, each writing the rows it
     owns and adding all of them to its replica counts; departures sum each
     chip's rows and add the sums over the mesh. Water-filling runs on the
-    mesh's first chip; ``waterfill_runs`` counts the calls per implementation, and
-    ``peak_flows`` / ``rounds`` record the largest flow table and the
-    fixed-point rounds summed over the run.
+    mesh's first chip; ``waterfill_runs`` counts the calls per path
+    (``"onehot"``, ``"xla"``), and ``peak_flows`` / ``rounds`` record the
+    largest flow table and the fixed-point rounds summed over the run.
+    ``interpret`` applies to the select kernel.
     """
 
     def __init__(self, jitter: np.ndarray, swarm_class: np.ndarray,
@@ -473,7 +449,7 @@ class FleetDeviceState:
         cls[:P] = swarm_class
         self.class_rows = jax.device_put(cls.reshape(width, LANES), whole)
         self.shard_rows_max = 0
-        self.waterfill_runs = {"pallas": 0, "onehot": 0, "xla": 0}
+        self.waterfill_runs = {"onehot": 0, "xla": 0}
         self.peak_flows = 0
         self.rounds = 0
 
@@ -543,9 +519,7 @@ class FleetDeviceState:
         """:func:`fleet_waterfill` on the device, keeping the run's
         water-fill statistics."""
         rates, rounds, plan = _waterfill(
-            src, dst, up_cap, down_cap, link_of, link_cap, None,
-            self.interpret,
-        )
+            src, dst, up_cap, down_cap, link_of, link_cap)
         self.waterfill_runs[plan.impl] += 1
         self.peak_flows = max(self.peak_flows, np.asarray(src).size)
         self.rounds += rounds

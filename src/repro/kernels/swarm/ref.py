@@ -12,15 +12,14 @@ Three oracles, three exactness contracts:
   (checksum-idiom pure-jnp): the XLA fixed point
   :func:`~.kernel.waterfill_xla` on the unpadded table, scatter-based.
   Comparing the device paths against it pins exactly what they add —
-  flow tiling, the padding conventions, the dummy link slot, the
-  kernel's one-hot segment math and the contraction's two-level one-hot
-  incidences — with zero tolerance.
+  the padding conventions, the dummy link slot and the contraction's
+  two-level one-hot incidences — with zero tolerance.
 
 - :func:`waterfill_f32_ref` — a float32 numpy transliteration of
   :func:`repro.core.fleet.waterfill_rates` (same bincount / min ordering,
   same ``newly``-freeze rule, ``1e-6`` saturation tolerance in place of
-  the float64 path's ``1e-12``). It is ulp-close to the kernel but not
-  bitwise: XLA:CPU unconditionally contracts ``alloc + count * delta``
+  the float64 path's ``1e-12``). It is ulp-close to the device paths but
+  not bitwise: XLA:CPU unconditionally contracts ``alloc + count * delta``
   into single-rounded FMAs, numpy rounds multiply and add separately, so
   cross-domain parity is pinned at a tight relative band instead. The
   float64 ``waterfill_rates`` remains the goldens semantics.
@@ -147,9 +146,8 @@ def waterfill_jnp_ref(
     scatter fixed point) on the unpadded, untiled table.
 
     Every device path must match this *bit for bit* — the diff is
-    precisely the machinery under test (the kernel's tiling and one-hot
-    segment sums, the contraction's two-level incidences; the paths'
-    padding and dummy slots). ``with_rounds`` returns ``(rates,
+    precisely the machinery under test (the contraction's two-level
+    incidences; the paths' padding and dummy slots). ``with_rounds`` returns ``(rates,
     rounds)``, the fixed point's rounds beside the rates.
     """
     src = np.asarray(src, dtype=np.int64)

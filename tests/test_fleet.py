@@ -105,20 +105,6 @@ def test_waterfill_empty():
     ).size == 0
 
 
-def test_jax_waterfill_matches_numpy():
-    jax = pytest.importorskip("jax")
-    del jax
-    from repro.core.fleet import _jax_waterfill
-
-    rng = np.random.default_rng(3)
-    for _ in range(10):
-        src, dst, up, dn, _, _ = random_topology(rng, with_links=False)
-        got = _jax_waterfill(src, dst, up, dn)
-        want = waterfill_rates(src, dst, up, dn)
-        # float32 kernel: throughput path, not a goldens path
-        np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
-
-
 # ------------------------------------------------------------------ selection
 
 
@@ -149,31 +135,33 @@ def test_fleet_spec_validation_and_roundtrip():
         FleetSpec(dt=0.0)
     with pytest.raises(ValueError):
         FleetSpec(fanout=0)
-    with pytest.warns(DeprecationWarning, match="backend='jit'"):
-        spec = FleetSpec(dt=0.5, fanout=3, jit=True)
+    spec = FleetSpec(dt=0.5, fanout=3, backend="pallas")
     assert FleetSpec.from_dict(spec.to_dict()) == spec
 
 
 def test_fleet_spec_backend_knob():
-    # normalization: the deprecated jit flag and the backend knob stay
-    # consistent in both directions
     assert FleetSpec().backend == "numpy"
-    assert FleetSpec(backend="jit").jit is True
-    with pytest.warns(DeprecationWarning, match="backend='jit'"):
-        legacy = FleetSpec(jit=True)
-    assert legacy.backend == "jit"
-    assert legacy == FleetSpec(backend="jit")
-    with pytest.raises(ValueError, match="numpy|jit|pallas"):
+    with pytest.raises(ValueError, match="'numpy' or 'pallas'"):
         FleetSpec(backend="cuda")
-    with pytest.raises(ValueError, match="conflicts"):
-        FleetSpec(jit=True, backend="numpy")
-    for backend in ("numpy", "jit", "pallas"):
+    for backend in ("numpy", "pallas"):
         spec = FleetSpec(backend=backend)
         assert FleetSpec.from_dict(spec.to_dict()) == spec
         assert spec.to_dict()["backend"] == backend
-    # pre-backend dicts (no "backend" key) still load
+        assert "jit" not in spec.to_dict()
+    # pre-backend dicts (no "backend" key, "jit": false) still load
     old = FleetSpec.from_dict({"dt": 1.0, "fanout": None, "jit": False})
-    assert old.backend == "numpy"
+    assert old == FleetSpec(dt=1.0)
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(lambda: FleetSpec(backend="jit"), id="backend"),
+    pytest.param(lambda: FleetSpec.from_dict({"jit": True}), id="from_dict"),
+])
+def test_fleet_spec_refuses_the_retired_jit_backend(build):
+    with pytest.raises(ValueError) as err:
+        build()
+    msg = str(err.value)
+    assert "'jit'" in msg and "'numpy'" in msg and "'pallas'" in msg
 
 
 def test_fleet_rejects_unsupported_policies():
@@ -336,7 +324,7 @@ def test_fleet_spec_refuses_devices_out_of_range(devices):
         FleetSpec(backend="pallas", devices=devices)
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jit"])
+@pytest.mark.parametrize("backend", ["numpy"])
 def test_fleet_spec_refuses_devices_off_the_device_backend(backend):
     with pytest.raises(ValueError, match="needs backend='pallas'"):
         FleetSpec(backend=backend, devices=4)

@@ -3,10 +3,10 @@
 Three exactness tiers (see ``repro/kernels/swarm/ref.py``):
 
 - rarest-argmin is *index-exact* against the numpy engine hot path;
-- every device water-fill path (the Pallas kernel, the padded one-hot
-  contraction and the padded scatter fixed point) is *bit-exact* against
-  the pure-jnp scatter oracle (tiling / padding / dummy-slot / one-hot
-  machinery adds nothing);
+- both device water-fill paths (the padded one-hot contraction and the
+  padded scatter fixed point), and the device state's entry point that
+  picks between them, are *bit-exact* against the pure-jnp scatter
+  oracle (padding / dummy-slot / one-hot machinery adds nothing);
 - against numpy references it holds a tight relative band (XLA:CPU fuses
   ``alloc + count * delta`` into FMAs; numpy rounds twice), and the
   engine-level test pins that the band never moves a piece completion on
@@ -120,12 +120,25 @@ def _random_topology(nf, nn, spine=False, inf_caps=False):
 
 @pytest.mark.parametrize("nf,nn", [(1, 2), (5, 3), (37, 10), (300, 40)])
 @pytest.mark.parametrize("spine", [False, True])
-@pytest.mark.parametrize("impl", ["xla", "pallas", "onehot"])
+@pytest.mark.parametrize("impl", ["xla", "onehot", "device"])
 def test_waterfill_bit_exact_vs_jnp_oracle(nf, nn, spine, impl):
     src, dst, up, dn, lof, lcap = _random_topology(nf, nn, spine=spine)
-    out = fleet_waterfill(src, dst, up, dn, lof, lcap, impl=impl)
-    ref = waterfill_jnp_ref(src, dst, up, dn, lof, lcap)
+    ref, ref_rounds = waterfill_jnp_ref(src, dst, up, dn, lof, lcap,
+                                        with_rounds=True)
+    if impl != "device":
+        out, rounds, _ = swarm_ops._waterfill(src, dst, up, dn, lof, lcap,
+                                              impl)
+    else:
+        # the engine's entry point: the plan's path, and the statistics
+        # the benchmark reads
+        dev = FleetDeviceState(np.zeros((1, 1), np.float32),
+                               np.zeros(1, bool))
+        out = dev.waterfill(src, dst, up, dn, lof, lcap)
+        assert dev.waterfill_runs == {"onehot": 1, "xla": 0}
+        assert dev.peak_flows == nf
+        rounds = dev.rounds
     np.testing.assert_array_equal(out.astype(np.float32), ref)
+    assert rounds == ref_rounds
 
 
 @pytest.mark.parametrize("spine", [False, True])
@@ -138,7 +151,7 @@ def test_waterfill_onehot_spans_node_blocks(spine):
     src, dst, _, _, lof, lcap = _random_topology(nf, nn, spine=spine)
     up, dn = np.full(nn, 25.0), np.full(nn, 50.0)
     rate, rounds, plan = swarm_ops._waterfill(
-        src, dst, up, dn, lof, lcap, "onehot", None)
+        src, dst, up, dn, lof, lcap, "onehot")
     assert (plan.pf, plan.pn) == (1 << 15, 1 << 12)
     ref, ref_rounds = waterfill_jnp_ref(src, dst, up, dn, lof, lcap,
                                         with_rounds=True)
@@ -148,8 +161,8 @@ def test_waterfill_onehot_spans_node_blocks(spine):
 
 def test_waterfill_bit_exact_with_inf_caps():
     src, dst, up, dn, lof, lcap = _random_topology(80, 12, inf_caps=True)
-    for impl in ("xla", "pallas", "onehot"):
-        out = fleet_waterfill(src, dst, up, dn, impl=impl)
+    for impl in ("xla", "onehot"):
+        out = swarm_ops._waterfill(src, dst, up, dn, None, None, impl)[0]
         np.testing.assert_array_equal(
             out.astype(np.float32), waterfill_jnp_ref(src, dst, up, dn)
         )
@@ -171,21 +184,22 @@ def test_waterfill_band_vs_numpy_refs():
 
 
 def test_waterfill_empty_and_zero_cap():
-    for impl in (None, "onehot"):
-        assert fleet_waterfill(
-            np.zeros(0, np.int64), np.zeros(0, np.int64),
-            np.ones(2), np.ones(2), impl=impl,
-        ).size == 0
+    assert fleet_waterfill(
+        np.zeros(0, np.int64), np.zeros(0, np.int64),
+        np.ones(2), np.ones(2),
+    ).size == 0
+    for impl in ("xla", "onehot"):
         # zero-capacity uplink: all its flows freeze at 0 immediately
-        out = fleet_waterfill(
+        out = swarm_ops._waterfill(
             np.zeros(4, np.int64), np.arange(1, 5),
-            np.array([0.0, 10, 10, 10, 10]), np.full(5, 10.0), impl=impl,
-        )
+            np.array([0.0, 10, 10, 10, 10]), np.full(5, 10.0), None, None,
+            impl,
+        )[0]
         np.testing.assert_array_equal(out, np.zeros(4))
 
 
 @pytest.mark.parametrize("nf,nn,want", [
-    (14_000, 2_001, "pallas"),     # the 2k crowd: the table fits VMEM
+    (14_000, 2_001, "onehot"),     # the 2k crowd's largest
     (90_000, 16_385, "onehot"),    # the one-chip ImageNet cell's largest
     (200_000, 100_001, "onehot"),  # the 100k crowd's (pn 2^17)
     (1_000_000, 524_288, "onehot"),  # the last node count it takes
@@ -409,9 +423,40 @@ def test_fleet_backend_pallas_raises_without_pallas(monkeypatch):
     assert sim.fleet_cfg.backend == "pallas"
 
 
-def test_fleet_backend_pallas_matches_numpy_engine():
+# the spine case's cross-pod link: about a fifth of the crowd's 5 GB/s
+# of uplinks, so that it binds while pieces circulate
+SPINE_BPS = 1e9
+
+
+def _smoke_sim(backend: str, spine: bool):
+    """The smoke scenario downsized to 200 clients on ``backend``; with
+    ``spine``, the clients alternate between two pods joined by a
+    :data:`SPINE_BPS` link that every cross-pod and origin flow crosses."""
+    from repro.core.fleet import FleetSwarmSim
+    from repro.core.scenario import ScenarioSpec
+
+    spec = json.loads((SCENARIOS / "fleet_smoke.json").read_text())
+    spec["arrivals"][0]["n"] = 200
+    spec["fleet"] = {"dt": 1.0, "fanout": None, "backend": backend}
+    scenario = ScenarioSpec.from_dict(spec)
+    if not spine:
+        return scenario.build("fleet").sim
+    mi, _ = scenario.content.manifests[0].build()
+    sim = FleetSwarmSim(mi, scenario.policy, scenario.swarm,
+                        fleet=scenario.fleet, seed=scenario.seed,
+                        num_pods=2, spine_bps=SPINE_BPS)
+    sim.add_mirrors(list(scenario.fabric.mirrors))
+    group = scenario.arrivals[0]
+    raw = group.generate()
+    sim.add_peers(raw, up_bps=group.up_bps, down_bps=group.down_bps,
+                  pods=np.arange(len(raw)) % 2)
+    return sim
+
+
+@pytest.mark.parametrize("spine", [False, True], ids=["flat", "spine"])
+def test_fleet_backend_pallas_matches_numpy_engine(spine):
     """backend="pallas" (interpret) reproduces the numpy engine on the
-    (downsized) smoke scenario.
+    (downsized) smoke scenario, flat and across a binding spine link.
 
     Piece selection is index-exact, so the *byte ledgers* — who downloaded
     what, piece-granular — match exactly. Completion *times* are compared
@@ -423,16 +468,8 @@ def test_fleet_backend_pallas_matches_numpy_engine():
     rechoke draws, after which individual trajectories decorrelate while
     the aggregate completion profile stays tight.
     """
-    from repro.core.scenario import ScenarioSpec
-
-    spec = json.loads((SCENARIOS / "fleet_smoke.json").read_text())
-    spec["arrivals"][0]["n"] = 200
-    results = {}
-    for backend in ("numpy", "pallas"):
-        spec["fleet"] = {"dt": 1.0, "fanout": None, "backend": backend}
-        compiled = ScenarioSpec.from_dict(spec).build("fleet")
-        sim = next(iter(compiled.sims.values()))
-        results[backend] = sim.run()
+    results = {backend: _smoke_sim(backend, spine).run()
+               for backend in ("numpy", "pallas")}
     ref, dev = results["numpy"], results["pallas"]
     assert ref.completed == dev.completed == dev.n == 200
     assert abs(dev.ticks - ref.ticks) <= max(5, 0.02 * ref.ticks)
@@ -452,3 +489,11 @@ def test_fleet_backend_pallas_matches_numpy_engine():
     assert set(dev.phase_seconds) == {
         "select", "waterfill", "bookkeeping", "telemetry"
     }
+    if spine:
+        # the link binds: on average over half full for the whole run
+        # (0.63 on the numpy engine; 349 ticks without it, 381 with it)
+        assert ref.spine_bytes > 0.5 * SPINE_BPS * ref.ticks * ref.dt
+        assert abs(dev.spine_bytes - ref.spine_bytes) \
+            <= 0.02 * ref.spine_bytes
+    else:
+        assert ref.spine_bytes == dev.spine_bytes == 0

@@ -145,13 +145,11 @@ def test_property_round_trip_randomized():
             )
             for i in range(draw(st.integers(1, 3)))
         )
+        # distinct times: two identical events are rightly refused
         events = tuple(
-            EventSpec(
-                kind="mirror_fail", at=draw(st.floats(0, 1e4,
-                                                      allow_nan=False)),
-                target=mirrors[0].name,
-            )
-            for _ in range(draw(st.integers(0, 2)))
+            EventSpec(kind="mirror_fail", at=at, target=mirrors[0].name)
+            for at in draw(st.lists(st.floats(0, 1e4, allow_nan=False),
+                                    max_size=2, unique=True))
         )
         return ScenarioSpec(
             content=ContentSpec(manifests=manifests),

@@ -180,7 +180,7 @@ def test_fleet_select_sharded_compiles_on_2x2(four_chips):
 
 
 def _waterfill_compiled(plan, sharding):
-    fn = swarm_ops._waterfill_jit(2 * plan.pn, plan.impl, False)
+    fn = swarm_ops._waterfill_jit(2 * plan.pn, plan.impl)
     flows = [((plan.pf,), jnp.int32)] * 3
     caps = [((plan.pn,), jnp.float32)] * 2 + [((plan.pnl,), jnp.float32)]
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
@@ -192,9 +192,9 @@ def _waterfill_hlo(plan, sharding):
     return _waterfill_compiled(plan, sharding).as_text()
 
 
-def test_waterfill_at_100k_runs_the_xla_fixed_point(one_chip):
-    # ~700k flows over 100,001 nodes: the kernel's table cannot fit VMEM,
-    # and pn 2^17 is within the one-hot contraction's reach
+def test_waterfill_at_100k_runs_the_onehot_contraction(one_chip):
+    # ~700k flows over 100,001 nodes: pn 2^17 is within the one-hot
+    # contraction's reach
     plan = swarm_ops.waterfill_plan(705_210, 100_001, 0)
     assert (plan.pf, plan.pn, plan.impl) == (1 << 20, 1 << 17, "onehot")
     hlo = _waterfill_hlo(plan, one_chip)
@@ -221,9 +221,14 @@ def test_waterfill_onehot_contracts_without_scatters(one_chip, pf, pn):
 
 def test_waterfill_kernel_compiles_where_the_plan_picks_it(one_chip):
     # the 2k-client crowd's largest table: ~14k flows over 2,001 nodes
+    # compiles as the contraction, with no Pallas call and no scatter
     plan = swarm_ops.waterfill_plan(14_000, 2_001, 0)
-    assert plan.impl == "pallas"
-    assert "tpu_custom_call" in _waterfill_hlo(plan, one_chip)
+    assert (plan.pf, plan.pn, plan.impl) == (1 << 14, 1 << 11, "onehot")
+    compiled = _waterfill_compiled(plan, one_chip)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    assert "scatter" not in {op for op, _ in _hlo_results(hlo)}
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 def test_checksum_compiles_at_1gib(one_chip):
