@@ -377,11 +377,11 @@ def _add_pieces_jit(mesh: Mesh):
         repl = repl.at[pieces].add(1, mode="drop")
         return have, repl
 
-    # over several chips the have matrix is updated in place: the warm-
-    # up's queued calls would each hold a copy of a chip's rows. One chip
-    # keeps its copy (ROADMAP P4: donating there moves the one-chip cell)
+    # the have matrix is donated, so the scatter writes each chip's rows
+    # in place: a copy would cost a pass over the whole matrix per call,
+    # and queued calls would each hold one
     return _mesh_jit(fleet_add_pieces, mesh, (ROW, WHOLE, WHOLE, WHOLE),
-                     (ROW, WHOLE), donate=(0,) if mesh.size > 1 else ())
+                     (ROW, WHOLE), donate=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -416,11 +416,13 @@ class FleetDeviceState:
     bucket sized by the busiest chip (``shard_rows_max`` keeps the last
     call's count); completions go to every chip, each writing the rows it
     owns and adding all of them to its replica counts; departures sum each
-    chip's rows and add the sums over the mesh. Water-filling runs on the
-    mesh's first chip; ``waterfill_runs`` counts the calls per path
-    (``"onehot"``, ``"xla"``), and ``peak_flows`` / ``rounds`` record the
-    largest flow table and the fixed-point rounds summed over the run.
-    ``interpret`` applies to the select kernel.
+    chip's rows and add the sums over the mesh. ``add_pieces`` consumes
+    the previous ``have_rows``: its scatter writes into that donated
+    buffer, so no caller may keep it across the call. Water-filling runs
+    on the mesh's first chip; ``waterfill_runs`` counts the calls per
+    path (``"onehot"``, ``"xla"``), and ``peak_flows`` / ``rounds``
+    record the largest flow table and the fixed-point rounds summed over
+    the run. ``interpret`` applies to the select kernel.
     """
 
     def __init__(self, jitter: np.ndarray, swarm_class: np.ndarray,

@@ -266,6 +266,32 @@ def test_device_state_tracks_incremental_updates():
     np.testing.assert_array_equal(np.asarray(dev.repl), repl)
 
 
+def test_device_state_add_pieces_consumes_the_have_matrix():
+    # one chip: each completion batch scatters into the donated have
+    # matrix, so the previous one is gone, and the state still follows
+    # the host's, an all-padding batch (the benchmark's warm-up) included
+    n, P = 40, 150
+    dev = FleetDeviceState(RNG.random((n, P), dtype=np.float32),
+                           RNG.random(P) < 0.5)
+    assert dev.devices == 1
+    have = np.zeros((n, P), dtype=bool)
+    repl = np.zeros(P, dtype=np.int64)
+    flat = RNG.permutation(n * P)[:60]  # unique pairs, in 4 batches
+    batches = [(flat[i:i + 15] // P, flat[i:i + 15] % P)
+               for i in range(0, 60, 15)]
+    batches.insert(2, (np.full(16, n), np.full(16, P)))  # all padding
+    for rows, pieces in batches:
+        old = dev.have_rows
+        dev.add_pieces(rows, pieces)
+        assert old.is_deleted()
+        assert not dev.have_rows.is_deleted()
+        held = rows < n
+        have[rows[held], pieces[held]] = True
+        np.add.at(repl, pieces[held], 1)
+    np.testing.assert_array_equal(dev.have, have)
+    np.testing.assert_array_equal(np.asarray(dev.repl), repl)
+
+
 def test_device_select_compiles_once_per_row_bucket():
     n, P = 300, 45
     dev = FleetDeviceState(RNG.random((n, P), dtype=np.float32),

@@ -179,6 +179,19 @@ def test_fleet_select_sharded_compiles_on_2x2(four_chips):
     assert drop.as_text().count("all-reduce(") == 1
 
 
+def test_fleet_add_pieces_donates_on_one_chip(one_chip):
+    # the one-chip ImageNet cell, 16,384 rows of 296 x 128 pieces: the
+    # completions scatter writes the have matrix in place, as over four
+    # chips, and needs no collective
+    n, P = 16384, 37504
+    width = swarm_ops.select_plan(P).width
+    mesh = _mesh_of(one_chip)
+    add = swarm_ops._add_pieces_jit(mesh).lower(
+        *_state_args(mesh, n, P, 16384, 16384)).compile()
+    assert "all-reduce" not in add.as_text()
+    assert add.memory_analysis().alias_size_in_bytes >= n * width * 128
+
+
 def _waterfill_compiled(plan, sharding):
     fn = swarm_ops._waterfill_jit(2 * plan.pn, plan.impl)
     flows = [((plan.pf,), jnp.int32)] * 3
